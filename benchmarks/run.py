@@ -1,14 +1,11 @@
-"""Benchmark harness: one entry per paper table/figure + roofline summary.
+"""Benchmark harness: one entry per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run
 
-Prints ``name,value,paper_or_derived[,rel_err]`` CSV lines and writes the
-roofline markdown table to benchmarks/results/roofline.md.
+Prints ``name,value,paper_or_derived[,rel_err]`` CSV lines.
 """
 
 from __future__ import annotations
-
-import pathlib
 
 
 def _emit(rows):
@@ -19,7 +16,7 @@ def _emit(rows):
 
 
 def main() -> None:
-    from benchmarks import api_bench, freq, roofline, sweep_bench, tables
+    from benchmarks import api_bench, freq, sweep_bench, tables
 
     print("# freq (paper §5.2)")
     _emit(freq.run())
@@ -33,22 +30,6 @@ def main() -> None:
     _emit(tables.run_table5())
     print("# design-space sweep engines")
     _emit(sweep_bench.run())
-
-    rows = roofline.run()
-    ok = [r for r in rows if r["status"] == "ok"]
-    print(f"# roofline ({len(ok)} ok cells of {len(rows)}; full table -> "
-          f"benchmarks/results/roofline.md)")
-    for r in ok:
-        print(f"roofline/{r['cell']},{r['roofline_fraction']},"
-              f"dominant={r['dominant']}")
-    if rows:     # only write a table when dry-run records exist
-        out = pathlib.Path(__file__).resolve().parent / "results" / "roofline.md"
-        out.parent.mkdir(parents=True, exist_ok=True)
-        out.write_text(roofline.markdown_table(rows) + "\n")
-        print(f"# wrote {out}")
-    else:
-        print("# no dry-run records under benchmarks/results/dryrun — "
-              "roofline table skipped")
 
 
 if __name__ == "__main__":

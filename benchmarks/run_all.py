@@ -13,7 +13,8 @@ engine and **fails on engine disagreement** — on end times (the
 phase-resolved Table 5 / mixed-trace energy totals (the matching
 asserts in ``tables.run_table5`` and ``sweep_bench.run_mixed``), and
 on the fleet-scale paths (``scale_bench``: streaming vs oracle,
-megakernel vs scan, sharded sweep == vmap), and on the FTL stage
+megakernel vs scan, sharded sweep == vmap when several devices are
+present), and on the FTL stage
 (``ftl_bench``: greedy WAF vs the analytic fixed point, the aging
 bandwidth cliff, GC-translated engine agreement) — and, in a full
 (non-smoke) run only, on a log-depth speedup < 1, a megakernel
@@ -109,8 +110,10 @@ def main() -> None:
     import jax
 
     from benchmarks import (api_bench, freq, ftl_bench, reliability_bench,
-                            roofline, scale_bench, sched_bench, sweep_bench,
-                            tables)
+                            scale_bench, sched_bench, sweep_bench, tables)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     t0 = time.perf_counter()
     sections = [
@@ -150,15 +153,6 @@ def main() -> None:
     ]
     _check_speedups(sections, args.smoke)
 
-    roof = roofline.run()
-    ok = [r for r in roof if r["status"] == "ok"]
-    print(f"# roofline: {len(ok)} ok cells of {len(roof)}")
-    if roof:
-        out = args.out / "roofline.md"
-        out.parent.mkdir(parents=True, exist_ok=True)
-        out.write_text(roofline.markdown_table(roof) + "\n")
-        print(f"# wrote {out}")
-
     args.out.mkdir(parents=True, exist_ok=True)
     n = args.index if args.index is not None else _next_index(args.out)
     payload = {
@@ -170,7 +164,6 @@ def main() -> None:
                 "python": platform.python_version(),
                 "machine": platform.machine()},
         "sections": sections,
-        "roofline_ok_cells": len(ok),
     }
     path = args.out / f"BENCH_{n}.json"
     path.write_text(json.dumps(payload, indent=1) + "\n")

@@ -3,9 +3,8 @@
 The paper evaluates ~60 design points with an RTL co-simulation.  This
 framework's contribution is making that sweep a data-parallel tensor
 program: we time (a) the plain-Python event loop, (b) the jit+vmap
-``lax.scan`` engine, (c) the (max,+) Pallas kernel (interpret on CPU;
-compiled on TPU — including the scalar-prefetch trace-indexed path),
-and (d) the **log-depth engines** (DESIGN.md §2.3) — periodic matrix
+``lax.scan`` engine, (c) the (max,+) Pallas kernel (interpreted
+off-TPU; its compiled TPU speed is not measured here), and (d) the **log-depth engines** (DESIGN.md §2.3) — periodic matrix
 squaring for the homogeneous grid and the segmented parallel-prefix
 fold for heterogeneous traces — over a channels × ways × interface ×
 cell × mode grid and over mixed-workload op traces.  ``run_logdepth``
@@ -87,7 +86,7 @@ def run(small: bool = False) -> list[dict]:
          "value": round(t_vm / n * 1e6, 1), "paper": "-"},
         {"name": "sweep/maxplus_interpret_us_per_point",
          "value": round(t_mp / n * 1e6, 1),
-         "paper": "(compiled Pallas on TPU)"},
+         "paper": "(interpret mode; TPU not measured)"},
         {"name": "sweep/vmap_speedup_vs_python",
          "value": round(t_ref / max(t_vm, 1e-9), 1), "paper": "-"},
     ] + run_mixed(small) + run_logdepth(small)
@@ -266,7 +265,7 @@ def run_logdepth(small: bool = False) -> list[dict]:
          "value": round(t_px / b * 1e6, 1), "paper": "-"},
         {"name": f"logdepth/mixed_T{t_pages}/dense_segmented_us_per_point",
          "value": round(t_seg / b * 1e6, 1),
-         "paper": "(MXU-shaped; compiled Pallas batching on TPU)"},
+         "paper": "(MXU-shaped; TPU not measured)"},
         {"name": f"logdepth/mixed_T{t_pages}/prefix_speedup_vs_scan",
          "value": round(t_scan_h / max(t_px, 1e-9), 2), "paper": ">1"},
         {"name": f"logdepth/mixed_T{t_pages}/prefix_speedup_vs_scan_batch",

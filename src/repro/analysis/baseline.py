@@ -12,10 +12,9 @@ the total jaxpr equation count measured at commit time.  The
   locking in with a baseline refresh;
 * baseline entry with no live fold — **info**: stale entry.
 
-The file also records the jax version it was measured under; on a
-version mismatch budget *errors* downgrade to info, because primitive
-counts legitimately move across jax releases — refresh the baseline
-instead of chasing phantom regressions.
+The file also records the jax version it was measured under, so a
+refresh after a jax upgrade shows in its diff; the gate fires on every
+version alike.
 """
 
 from __future__ import annotations
@@ -54,8 +53,6 @@ def load_baseline(path: Path = DEFAULT_BASELINE) -> dict | None:
 
 def check_budgets(folds: list[EngineFold],
                   baseline: dict | None) -> list[Finding]:
-    import jax
-
     if baseline is None:
         return [Finding(
             rule="jaxpr-budget", path=str(DEFAULT_BASELINE), line=0,
@@ -63,16 +60,6 @@ def check_budgets(folds: list[EngineFold],
                     "repro.analysis --baseline` and commit the result")]
 
     findings: list[Finding] = []
-    jax_matches = baseline.get("jax") == jax.__version__
-    severity = "error" if jax_matches else "info"
-    if not jax_matches:
-        findings.append(Finding(
-            rule="jaxpr-budget", path="baseline.json", line=0,
-            severity="info",
-            message=f"baseline measured under jax {baseline.get('jax')}, "
-                    f"running {jax.__version__}: budget regressions "
-                    "downgraded to info — refresh the baseline"))
-
     budgets = dict(baseline.get("budgets", {}))
     hosts = set(baseline.get("host_engines", []))
     for fold in folds:
@@ -80,7 +67,6 @@ def check_budgets(folds: list[EngineFold],
             if fold.engine not in hosts:
                 findings.append(Finding(
                     rule="jaxpr-budget", path=fold.key, line=0,
-                    severity=severity,
                     message=f"host engine {fold.engine!r} not recorded "
                             "in baseline (run --baseline)"))
             continue
@@ -88,7 +74,6 @@ def check_budgets(folds: list[EngineFold],
         if budget is None:
             findings.append(Finding(
                 rule="jaxpr-budget", path=fold.key, line=0,
-                severity=severity,
                 message=f"fold not in baseline ({fold.n_primitives} "
                         "primitives measured); run --baseline"))
             continue
@@ -97,7 +82,6 @@ def check_budgets(folds: list[EngineFold],
         if fold.n_primitives > hi:
             findings.append(Finding(
                 rule="jaxpr-budget", path=fold.key, line=0,
-                severity=severity,
                 message=f"primitive count {fold.n_primitives} exceeds "
                         f"budget {budget} (+{BUDGET_TOLERANCE:.0%} = "
                         f"{hi}): compile-size regression"))

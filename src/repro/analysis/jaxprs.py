@@ -16,7 +16,7 @@ contracts the engines' bit-for-bit claim rests on:
 ``jaxpr-dtype``
     no f64 value anywhere, floating outputs exactly f32.  Each fold is
     traced twice: once under the default config and once under
-    ``jax.experimental.enable_x64`` — f32 discipline must come from
+    ``jax.enable_x64(True)`` — f32 discipline must come from
     explicit dtypes, not from the global f64 demotion silently papering
     over weak-type promotion;
 ``pad-identity``
@@ -146,7 +146,7 @@ def _check_one(engine: str, label: str, fn, args,
 
     # Retrace with x64 enabled: a weak python-float constant that the
     # default config silently demotes to f32 promotes to f64 here.
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed64 = jax.make_jaxpr(fn)(*args)
     f64_hits_x64: list[str] = []
 

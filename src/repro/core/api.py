@@ -2029,7 +2029,7 @@ def _points_mesh():
 
 
 def _shard_points(mesh, fn, *, n_sharded: int):
-    from repro.distributed.partitioning import shard_points
+    from repro.launch.mesh import shard_points
     return shard_points(mesh, fn, n_sharded=n_sharded)
 
 

@@ -745,8 +745,9 @@ def trace_end_time_prefix(
     products into the initial state with O(S) cheap (max,+) matvecs
     (fastest on CPU); ``combine="assoc"`` combines them with a
     log-depth ``associative_scan`` of dense matmuls — O(L + log S)
-    total depth, the shape that pays on TPU.  Compiles end to end from
-    the raw table/trace arrays with no Python pass over the trace.
+    total depth, the shape meant for the TPU's MXU (its TPU speed is not
+    measured yet).  Compiles end to end from the raw table/trace arrays
+    with no Python pass over the trace.
 
     ``n_ways`` bounds the way indices in the trace and sets the state
     layout (smaller than the scan engine's fixed MAX_WAYS block, so the

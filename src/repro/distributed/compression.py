@@ -19,7 +19,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -53,7 +52,7 @@ def make_dp_grad_sync(mesh: Mesh, axis: str = "data", compress: bool = True):
 
     def wrapped(grads):
         specs = jax.tree.map(lambda _: P(), grads)
-        return shard_map(sync, mesh=mesh, in_specs=(specs,), out_specs=specs)(grads)
+        return jax.shard_map(sync, mesh=mesh, in_specs=(specs,), out_specs=specs)(grads)
 
     return wrapped
 

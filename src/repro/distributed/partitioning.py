@@ -39,7 +39,6 @@ import re
 from typing import Any
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.transformer import LayerSpec, ModelConfig
@@ -313,56 +312,3 @@ def cache_pspecs(cfg: ModelConfig, mesh: Mesh, cache_shape: Any) -> Any:
 def shardings(mesh: Mesh, pspecs: Any) -> Any:
     return jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
                         is_leaf=lambda x: isinstance(x, P))
-
-
-# ---------------------------------------------------------------------------
-# design-point sweep sharding (simulator batches)
-# ---------------------------------------------------------------------------
-
-POINTS_AXIS = "points"
-
-
-def points_spec(ndim: int) -> P:
-    """Leading axis over ``points``, everything else replicated."""
-    return P(POINTS_AXIS, *([None] * (ndim - 1)))
-
-
-def shard_points(mesh: Mesh, fn, *, n_sharded: int):
-    """Wrap a batched-over-leading-axis ``fn`` with ``jax.shard_map``
-    over the 1-D ``("points",)`` sweep mesh (``launch.mesh.
-    make_points_mesh``): the first ``n_sharded`` arguments shard their
-    leading axis across devices, the rest (shared trace arrays)
-    replicate, and the [B] output gathers back.
-
-    The batch pads to a device multiple by repeating row 0 — padded
-    rows simulate harmless garbage that is sliced off before returning,
-    so callers see exactly their B results.  The shard_map program is
-    built (and jitted) once per argument-rank signature and reused, so
-    repeated sweeps through one wrapper stay retrace-free."""
-    from jax.experimental.shard_map import shard_map
-
-    size = axis_size(mesh, POINTS_AXIS)
-    compiled: dict[tuple[int, ...], Any] = {}
-
-    def call(*arrays):
-        arrays = tuple(jnp.asarray(a) for a in arrays)
-        n = int(arrays[0].shape[0])
-        pad = -n % size
-        if pad:
-            head = arrays[:n_sharded]
-            arrays = tuple(
-                jnp.concatenate([a, jnp.repeat(a[:1], pad, axis=0)])
-                for a in head) + arrays[n_sharded:]
-        key = tuple(a.ndim for a in arrays)
-        sm = compiled.get(key)
-        if sm is None:
-            specs = tuple(
-                points_spec(a.ndim) if i < n_sharded
-                else P(*([None] * a.ndim))
-                for i, a in enumerate(arrays))
-            sm = compiled[key] = jax.jit(shard_map(
-                fn, mesh=mesh, in_specs=specs,
-                out_specs=P(POINTS_AXIS)))
-        return sm(*arrays)[:n]
-
-    return call

@@ -12,8 +12,8 @@ Two entry points mirror the two scan-engine paths in ``repro.core``:
 Both take a ``strategy`` (DESIGN.md §2.3):
 
 * ``"sequential"`` — the O(T) Pallas ``fori_loop`` matvec fold
-  (``repro.kernels.maxplus.kernel``; compiles on TPU for both the
-  periodic and the scalar-prefetch trace-indexed path);
+  (``repro.kernels.maxplus.kernel``; ``interpret=None`` compiles it on a
+  TPU and interprets it elsewhere);
 * ``"segmented"`` — the segmented parallel-prefix matmul fold,
   O(segment_len + log T) depth;
 * ``"squaring"`` (homogeneous only) — periodic matrix squaring,
@@ -106,10 +106,6 @@ def maxplus_fold(mats, s0, *, t_steps: int, idx=None, use_kernel: bool = True,
     if strategy != "sequential":
         raise ValueError(f"unknown strategy {strategy!r} (one of "
                          "'sequential', 'segmented', 'squaring')")
-    if interpret is None:
-        # both kernel paths compile on TPU (the trace-indexed one via
-        # SMEM scalar prefetch); interpret only off-TPU
-        interpret = jax.default_backend() != "tpu"
     if use_kernel:
         return maxplus_fold_kernel(mats, s0, t_steps=t_steps, idx=idx,
                                    arrivals=arrivals, gvec=gvec,
@@ -290,8 +286,6 @@ def run_many_end_time_maxplus(
         if tr.extra_us is not None:
             ext[lane, :tr.n_ops] = np.asarray(tr.extra_us, np.float32)
         lengths[lane] = tr.n_ops
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     # fault surcharges ride a written-rows mask over the union dictionary
     # (zero row for the padding identity); all-zero fleets compile the
     # shift out so fault-free runs stay bit-identical
@@ -352,8 +346,6 @@ def trace_energy_maxplus(
     e = np.stack([combo_energy_uj(table, combos, kind)
                   for table, kind in zip(tables, kinds)])
     if strategy == "sequential":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         if use_kernel:
             final, acc = maxplus_fold_kernel(
                 jnp.asarray(mats), jnp.asarray(s0), t_steps=trace.n_ops,

@@ -9,20 +9,32 @@ before the first JAX initialisation.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+POINTS_AXIS = "points"
+
+
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axes: callers index, slice and gather
+    sharded arrays as plain arrays, which explicit (typed) axes — the
+    default of ``jax.make_mesh`` — would reject."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     """Development mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_points_mesh() -> jax.sharding.Mesh | None:
@@ -36,7 +48,51 @@ def make_points_mesh() -> jax.sharding.Mesh | None:
     n = len(jax.devices())
     if n < 2:
         return None
-    return jax.make_mesh((n,), ("points",))
+    return _auto_mesh((n,), (POINTS_AXIS,))
+
+
+def shard_points(mesh: jax.sharding.Mesh, fn, *, n_sharded: int):
+    """Wrap a batched-over-leading-axis ``fn`` with ``jax.shard_map``
+    over the 1-D ``("points",)`` mesh of :func:`make_points_mesh`: every
+    leaf of the first ``n_sharded`` arguments (arrays or pytrees of
+    them) shards its leading axis across devices, the rest (shared trace
+    arrays) replicate, and every output leaf stays sharded over the
+    points.
+
+    The batch pads to a device multiple by repeating row 0 — padded
+    rows simulate harmless garbage that is sliced off every output leaf
+    before returning, so callers see exactly their B results.  The
+    shard_map program is built (and jitted) once per argument count and
+    reused, so repeated sweeps through one wrapper stay retrace-free."""
+    size = mesh.devices.size
+    programs: dict[int, object] = {}
+
+    def grow(a, pad):
+        a = jnp.asarray(a)
+        return jnp.concatenate([a, jnp.repeat(a[:1], pad, axis=0)])
+
+    def call(*args):
+        n = int(jax.tree.leaves(args[0])[0].shape[0])
+        pad = -n % size
+        if pad:
+            args = tuple(jax.tree.map(lambda a: grow(a, pad), x)
+                         if i < n_sharded else x
+                         for i, x in enumerate(args))
+        sm = programs.get(len(args))
+        if sm is None:
+            # each device folds its own points with no collective, so
+            # there is nothing for the varying-axis check to verify; its
+            # scan-carry rule would reject the folds' replicated initial
+            # states
+            specs = tuple(P(POINTS_AXIS) if i < n_sharded else P()
+                          for i in range(len(args)))
+            sm = programs[len(args)] = jax.jit(jax.shard_map(
+                fn, mesh=mesh, in_specs=specs, out_specs=P(POINTS_AXIS),
+                check_vma=False))
+        out = sm(*args)
+        return jax.tree.map(lambda x: x[:n], out) if pad else out
+
+    return call
 
 
 def mesh_chip_count(mesh: jax.sharding.Mesh) -> int:

@@ -11,9 +11,8 @@ from repro.launch.steps import abstract_train_state, train_state_pspecs
 from repro.models.transformer import init_cache, init_params
 from repro.train.optimizer import OptConfig
 
-# jax 0.4.37's AbstractMesh takes a shape_tuple of (name, size) pairs
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _specs(name, mesh=MESH):
