@@ -1,0 +1,180 @@
+"""The simulator's own spans, read from a profiler trace.
+
+The program opens a ``jax.profiler.TraceAnnotation`` named ``ssd.<stage>``
+at each layer boundary of a query (``repro/core/spans.py`` lists them and
+their stats).  They sit on the host plane beside the harness's
+``bench.*`` spans, on the clock of the device programs.  This module
+keeps them, with their stats, and reduces them to the per-stage numbers
+below.  A trace of a program without such spans gives no spans, and
+every number here is then ``None``.
+
+A span is ``(stage, start_ns, end_ns, stats)``, the stage without the
+``ssd.`` prefix.  Spans nest by containment on the calling thread.
+
+| number | reads |
+| --- | --- |
+| ``stage_ms_per_query`` | time in ``ssd.stage`` per query |
+| ``fold_wall_ms_per_query`` | time in ``ssd.fold`` per query, on the host clock |
+| ``lower_ms_per_query`` | time in ``ssd.lower`` per query |
+| ``extract_ms_per_query`` | time in ``ssd.extract`` per query |
+| ``precondition_ms_per_query`` | time in ``ssd.precondition`` per query |
+| ``translate_ms_per_query`` | time in ``ssd.translate`` outside ``ssd.precondition``, per query |
+| ``fold_pad_pct`` | 100 (1 - sum of ``ops`` / sum of ``steps``) over ``ssd.fold`` |
+| ``ftl_machine_runs_per_query`` | sum of ``machine_runs`` of ``ssd.translate`` per query |
+"""
+
+from __future__ import annotations
+
+import re
+
+from trace_reduce import covered, gaps
+
+PREFIX = "ssd."
+_DEVICE = re.compile(r"^/device:TPU:(\d+)$")
+
+
+def from_events(host_events, window) -> list[tuple]:
+    """The program spans of ``host_events`` = [(name, start_ns, dur_ns,
+    stats)], names as the trace has them, clipped to ``window``; spans
+    wholly outside it are dropped."""
+    lo, hi = window
+    out = []
+    for n, s, d, stats in host_events:
+        if not n.startswith(PREFIX) or s + d <= lo or s >= hi:
+            continue
+        out.append((n[len(PREFIX):], max(s, lo), min(s + d, hi),
+                    dict(stats)))
+    return sorted(out, key=lambda sp: (sp[1], -sp[2]))
+
+
+def read_xplane(path: str) -> tuple[list[tuple], dict[str, dict]]:
+    """From one read of an ``.xplane.pb`` file: [(name, start_ns,
+    dur_ns, stats)] of every ``ssd.`` event on its host planes, and the
+    events per line of each device plane (where a line holds fewer
+    programs than were run, the profiler dropped events)."""
+    from jax.profiler import ProfileData
+
+    spans, lines = [], {}
+    for plane in ProfileData.from_file(path).planes:
+        if _DEVICE.match(plane.name):
+            lines[plane.name] = {line.name: sum(1 for _ in line.events)
+                                 for line in plane.lines}
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans.extend((e.name, e.start_ns, e.duration_ns,
+                              dict(e.stats))
+                             for e in line.events
+                             if e.name.startswith(PREFIX))
+    return spans, lines
+
+
+def stage_ms(spans, stage: str) -> float:
+    """Milliseconds in which some span of ``stage`` was open."""
+    return covered([(a, b) for n, a, b, _ in spans if n == stage],
+                   float("-inf"), float("inf")) * 1e-6
+
+
+def _per_query(spans, stage: str, queries: int):
+    if not queries or not any(n == stage for n, *_ in spans):
+        return None
+    return stage_ms(spans, stage) / queries
+
+
+def stage_ms_per_query(spans, queries):
+    return _per_query(spans, "stage", queries)
+
+
+def fold_wall_ms_per_query(spans, queries):
+    return _per_query(spans, "fold", queries)
+
+
+def lower_ms_per_query(spans, queries):
+    return _per_query(spans, "lower", queries)
+
+
+def extract_ms_per_query(spans, queries):
+    return _per_query(spans, "extract", queries)
+
+
+def precondition_ms_per_query(spans, queries):
+    return _per_query(spans, "precondition", queries)
+
+
+def translate_ms_per_query(spans, queries):
+    """The translation's own time: ``ssd.translate`` less the
+    ``ssd.precondition`` inside it."""
+    whole = _per_query(spans, "translate", queries)
+    if whole is None:
+        return None
+    trans = [(a, b) for n, a, b, _ in spans if n == "translate"]
+    pre = sum(covered(trans, a, b)
+              for n, a, b, _ in spans if n == "precondition")
+    return whole - pre * 1e-6 / queries
+
+
+def fold_pad_pct(spans, queries=None):
+    """Share of the folds' scan steps that ran on padding."""
+    folds = [st for n, _, _, st in spans if n == "fold"]
+    steps = sum(int(st.get("steps", 0)) for st in folds)
+    if not steps:
+        return None
+    ops = sum(int(st.get("ops", 0)) for st in folds)
+    return 100.0 * (1.0 - ops / steps)
+
+
+def ftl_machine_runs_per_query(spans, queries):
+    """Launches of the translation fold per query, preconditioning
+    included."""
+    runs = [st["machine_runs"] for n, _, _, st in spans
+            if n == "translate" and "machine_runs" in st]
+    if not queries or not runs:
+        return None
+    return sum(int(r) for r in runs) / queries
+
+
+#: Every per-stage number, by name.
+METRICS = {f.__name__: f for f in (
+    stage_ms_per_query, fold_wall_ms_per_query, lower_ms_per_query,
+    extract_ms_per_query, precondition_ms_per_query,
+    translate_ms_per_query, fold_pad_pct, ftl_machine_runs_per_query)}
+
+
+def query_coverage(spans, queries) -> list[float]:
+    """For each query span (start_ns, end_ns), the share of it that some
+    program span covers."""
+    ivs = [(a, b) for _, a, b, _ in spans]
+    return [covered(ivs, a, b) / (b - a) if b > a else 1.0
+            for a, b in queries]
+
+
+def fold_device_cover(spans, device_intervals) -> list[dict]:
+    """For each ``ssd.fold`` span, its stats, its length and the share of
+    it in which a device ran a program."""
+    out = []
+    for n, a, b, st in spans:
+        if n == "fold":
+            out.append({**st, "ms": (b - a) * 1e-6,
+                        "device_share": (covered(device_intervals, a, b)
+                                         / (b - a) if b > a else 0.0)})
+    return out
+
+
+def innermost_idle(r, spans, top: int = 12) -> list[list]:
+    """The traced window's idle time (no device ran a program), put down
+    to the innermost span open then: a program span (``ssd.<stage>``)
+    or a harness span (``query``, ``generate``), else ``harness`` (the
+    loop between spans).  ``r`` is the harness's ``trace_reduce.Reduced``.
+    Seconds, largest first."""
+    opened = ([("ssd." + n, a, b) for n, a, b, _ in spans]
+              + [(n, a, b) for n, a, b in r.spans if n != "window"])
+    idle: dict[str, float] = {}
+    for lo, hi in gaps(r.all_devices_intervals(), *r.window):
+        cuts = sorted({lo, hi} | {t for _, a, b in opened for t in (a, b)
+                                  if lo < t < hi})
+        for x, y in zip(cuts, cuts[1:]):
+            inside = [(a, -b, n) for n, a, b in opened if a <= x and y <= b]
+            name = max(inside)[2] if inside else "harness"
+            idle[name] = idle.get(name, 0.0) + (y - x) * 1e-9
+    return [[n, s] for n, s in sorted(idle.items(), key=lambda kv: -kv[1])
+            [:top]]
+

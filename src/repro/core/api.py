@@ -77,6 +77,7 @@ from repro.core import ftl as _ftl
 from repro.core import ftl_scan as _ftl_scan
 from repro.core import sched as _sched
 from repro.core import sim as _sim
+from repro.core import spans as _spans
 from repro.core import trace as _trace
 from repro.core import workload as _workload
 from repro.core.energy import (EnergyBreakdown, breakdown_from_sums,
@@ -284,6 +285,13 @@ def _stacked_table_args(tables: list[OpClassTable]):
                  for f in _TABLE_FIELDS)
 
 
+def _staged_batch_args(tables: list[OpClassTable], trace: OpTrace):
+    """The stacked tables and the trace as device arguments of a
+    table-batched fold, under the ``ssd.stage`` span."""
+    with _spans.span("stage", steps=trace.n_ops):
+        return _stacked_table_args(tables) + _trace_args(trace)
+
+
 def _canonical_trace(arrivals: bool = True) -> OpTrace:
     """The canonical small request the analysis layer traces every
     engine on (DESIGN.md §2.9): a fixed-seed mixed read/write trace on
@@ -382,7 +390,11 @@ class ScanEngine(_EngineBase):
             lambda: functools.partial(
                 _sim.trace_end_time_masked, *sim._targs,
                 n_channels=trace.channels, batched=batched))
-        return float(fn(*_padded_trace_args(trace, t_b)))
+        with _spans.span("stage", steps=t_b):
+            args = _padded_trace_args(trace, t_b)
+        with _spans.span("fold", engine="scan", ops=trace.n_ops, steps=t_b,
+                         points=1):
+            return float(fn(*args))
 
     def completions(self, sim, trace, *, batched, segment_len=None):
         t_b = _bucket_len(trace.n_ops)
@@ -391,8 +403,12 @@ class ScanEngine(_EngineBase):
             lambda: functools.partial(
                 _sim.trace_completions_masked, *sim._targs,
                 n_channels=trace.channels, batched=batched))
-        end, comp = fn(*_padded_trace_args(trace, t_b))
-        return float(end), np.asarray(comp, np.float64)[: trace.n_ops]
+        with _spans.span("stage", steps=t_b):
+            args = _padded_trace_args(trace, t_b)
+        with _spans.span("fold", engine="scan", ops=trace.n_ops, steps=t_b,
+                         points=1):
+            end, comp = fn(*args)
+            return float(end), np.asarray(comp, np.float64)[: trace.n_ops]
 
     def dispatch_run(self, sim, cls, arrival_us, *, n_channels, n_ways,
                      rule, extra_us=None, retired=None):
@@ -402,16 +418,19 @@ class ScanEngine(_EngineBase):
             lambda: functools.partial(
                 _sim.dispatch_trace, *sim._targs,
                 n_channels=n_channels, n_ways=n_ways, rule=rule))
-        kw = {}
-        if extra_us is not None:
-            kw["extra_us"] = jnp.asarray(extra_us, jnp.float32)
-        if retired is not None:
-            kw["retired"] = jnp.asarray(retired, bool)
-        end, comp, chan, way, par = fn(jnp.asarray(cls, jnp.int32),
-                                       jnp.asarray(arrival_us, jnp.float32),
-                                       **kw)
-        return (float(end), np.asarray(comp, np.float64),
-                np.asarray(chan), np.asarray(way), np.asarray(par))
+        n = len(cls)
+        with _spans.span("stage", steps=n):
+            kw = {}
+            if extra_us is not None:
+                kw["extra_us"] = jnp.asarray(extra_us, jnp.float32)
+            if retired is not None:
+                kw["retired"] = jnp.asarray(retired, bool)
+            args = (jnp.asarray(cls, jnp.int32),
+                    jnp.asarray(arrival_us, jnp.float32))
+        with _spans.span("fold", engine="scan", ops=n, steps=n, points=1):
+            end, comp, chan, way, par = fn(*args, **kw)
+            return (float(end), np.asarray(comp, np.float64),
+                    np.asarray(chan), np.asarray(way), np.asarray(par))
 
     def energy_sums(self, sim, trace, kind, *, batched, segment_len):
         fn = sim._closure(
@@ -425,10 +444,11 @@ class ScanEngine(_EngineBase):
 
     def end_time_batch(self, tables, trace, *, batched, segment_len,
                        combine="chain"):
-        end = _sim.trace_end_time_batch(
-            *_stacked_table_args(tables), *_trace_args(trace),
-            n_channels=trace.channels, batched=batched)
-        return np.asarray(end)
+        args = _staged_batch_args(tables, trace)
+        with _spans.span("fold", engine="scan", ops=trace.n_ops,
+                         steps=trace.n_ops, points=len(tables)):
+            return np.asarray(_sim.trace_end_time_batch(
+                *args, n_channels=trace.channels, batched=batched))
 
     def steady_channel_end(self, op, ways, *, n_pages, batched):
         table, zeros, way, parity, arr, ext = _steady_channel_args(
@@ -526,11 +546,13 @@ class PrefixEngine(_EngineBase):
 
     def end_time_batch(self, tables, trace, *, batched, segment_len,
                        combine="chain"):
-        end = _sim.trace_end_time_prefix_batch(
-            *_stacked_table_args(tables), *_trace_args(trace),
-            n_channels=trace.channels, n_ways=trace.ways, batched=batched,
-            segment_len=segment_len, combine=combine)
-        return np.asarray(end)
+        args = _staged_batch_args(tables, trace)
+        with _spans.span("fold", engine="prefix", ops=trace.n_ops,
+                         steps=trace.n_ops, points=len(tables)):
+            return np.asarray(_sim.trace_end_time_prefix_batch(
+                *args, n_channels=trace.channels, n_ways=trace.ways,
+                batched=batched, segment_len=segment_len,
+                combine=combine))
 
     def steady_channel_end(self, op, ways, *, n_pages, batched):
         table, zeros, way, parity, arr, ext = _steady_channel_args(
@@ -1033,6 +1055,9 @@ class Simulator:
             collections.OrderedDict()
         self._ftl_sweep_sizes: collections.OrderedDict[
             tuple, tuple[int, int]] = collections.OrderedDict()
+        #: ``run`` and ``sweep`` calls so far: the ``call`` stat of
+        #: their root spans
+        self._calls = 0
 
     # -- shared per-config sessions ----------------------------------------
 
@@ -1170,8 +1195,16 @@ class Simulator:
             request = SimRequest(trace=request, **overrides)
         elif overrides:
             request = dataclasses.replace(request, **overrides)
-        if request.workload is not None:
-            return self._run_workload(request)
+        self._calls += 1
+        ops = (request.trace.n_ops if request.workload is None
+               else request.workload.n_requests)
+        with _spans.span("run", call=self._calls, ops=ops, points=1):
+            if request.workload is not None:
+                return self._run_workload(request)
+            return self._run_trace(request)
+
+    def _run_trace(self, request: SimRequest) -> SimResult:
+        """Trace queries: an already placed ``OpTrace`` on one engine."""
         trace = request.trace
         if trace.n_ops == 0:
             raise ValueError("empty trace: no ops to simulate")
@@ -1191,8 +1224,9 @@ class Simulator:
         else:
             end_us = eng.end_time(self, trace, batched=batched,
                                   segment_len=request.segment_len)
-        return self._result(trace, end_us, eng.caps.name, energy,
-                            sampler=sampler)
+        with _spans.span("extract"):
+            return self._result(trace, end_us, eng.caps.name, energy,
+                                sampler=sampler)
 
     def _ftl_session(self, spec: "_ftl.FTLSpec") -> "Simulator":
         """Memoised sibling session over the 7-class FTL op table
@@ -1309,12 +1343,13 @@ class Simulator:
                     extra_us=(None if ext is None
                               else np.asarray(ext, np.float32)))
                 return tr, end, comp
-            tr = _sched.lower_ops(cls, arr, channels, ways, policy_s,
-                                  payload=pay)
-            if ext is not None:
-                tr = dataclasses.replace(
-                    tr, extra_us=np.asarray(ext, np.float32))
-            tr.validate_against(sess.table)
+            with _spans.span("lower", ops=len(cls)):
+                tr = _sched.lower_ops(cls, arr, channels, ways, policy_s,
+                                      payload=pay)
+                if ext is not None:
+                    tr = dataclasses.replace(
+                        tr, extra_us=np.asarray(ext, np.float32))
+                tr.validate_against(sess.table)
             base = getattr(_EngineBase, "completions")
             if want_comp and getattr(type(eng), "completions",
                                      base) is not base:
@@ -1332,13 +1367,14 @@ class Simulator:
             # GC ops belong to no request (request_id -1): latency
             # accounting sees host ops only — but over the *aged*
             # completion times, so GC queueing is in the tail
-            host = translation.request_id >= 0
-            lowered = LoweredWorkload(
-                trace=trace, request_id=translation.request_id[host],
-                request_arrival_us=np.asarray(stream.arrival_us,
-                                              np.float32))
-            lat = _payload_latencies(lowered, np.asarray(comp)[host],
-                                     stream)
+            with _spans.span("extract"):
+                host = translation.request_id >= 0
+                lowered = LoweredWorkload(
+                    trace=trace, request_id=translation.request_id[host],
+                    request_arrival_us=np.asarray(stream.arrival_us,
+                                                  np.float32))
+                lat = _payload_latencies(lowered, np.asarray(comp)[host],
+                                         stream)
         energy = None
         if request.objective in ("energy", "all"):
             # energy is (+,+)-linear, so the engine-free per-op sum is
@@ -1354,9 +1390,10 @@ class Simulator:
             if fresh_payload > 0:
                 fresh_mb_s = fresh_payload / fresh_end
         stats = translation.stats
-        res = sess._result(trace, end_us, eng.caps.name, energy,
-                           request_lat_us=lat, sched_policy=policy_s,
-                           sampler=sampler)
+        with _spans.span("extract"):
+            res = sess._result(trace, end_us, eng.caps.name, energy,
+                               request_lat_us=lat, sched_policy=policy_s,
+                               sampler=sampler)
         return dataclasses.replace(
             res, waf=stats.waf, gc_op_count=stats.gc_op_count,
             free_page_low_watermark=stats.free_page_low_watermark,
@@ -1440,11 +1477,12 @@ class Simulator:
                 arrival_us=arrival,
                 extra_us=(None if extra is None
                           else np.asarray(extra, np.float32)))
-            lowered = LoweredWorkload(
-                trace=trace, request_id=req_id,
-                request_arrival_us=np.asarray(stream.arrival_us,
-                                              np.float32))
-            lat = _payload_latencies(lowered, comp, stream)
+            with _spans.span("extract"):
+                lowered = LoweredWorkload(
+                    trace=trace, request_id=req_id,
+                    request_arrival_us=np.asarray(stream.arrival_us,
+                                                  np.float32))
+                lat = _payload_latencies(lowered, comp, stream)
             energy = None
             if request.objective in ("energy", "all"):
                 # energy is (+,+)-linear: the dispatched placement fixes
@@ -1452,26 +1490,29 @@ class Simulator:
                 # exact (DESIGN.md §2.4)
                 energy = self._breakdown(
                     self._linear_energy_sums(trace, self.kind), end, trace)
-            return self._result(trace, end, eng.caps.name, energy,
-                                request_lat_us=lat, sched_policy=policy_s,
-                                sampler=sampler)
-        lowered = _sched.lower_static(stream, channels, ways, policy_s)
-        trace = lowered.trace
-        sampler = None
-        if spec is not None:
-            trace, rid2, sampler = _sched.apply_faults(
-                trace, spec, self.table, request_id=lowered.request_id)
-            lowered = LoweredWorkload(
-                trace=trace, request_id=rid2,
-                request_arrival_us=lowered.request_arrival_us)
-        trace.validate_against(self.table)
+            with _spans.span("extract"):
+                return self._result(trace, end, eng.caps.name, energy,
+                                    request_lat_us=lat,
+                                    sched_policy=policy_s, sampler=sampler)
+        with _spans.span("lower", ops=stream.n_requests):
+            lowered = _sched.lower_static(stream, channels, ways, policy_s)
+            trace = lowered.trace
+            sampler = None
+            if spec is not None:
+                trace, rid2, sampler = _sched.apply_faults(
+                    trace, spec, self.table, request_id=lowered.request_id)
+                lowered = LoweredWorkload(
+                    trace=trace, request_id=rid2,
+                    request_arrival_us=lowered.request_arrival_us)
+            trace.validate_against(self.table)
         energy = None
         lat = None
         base = getattr(_EngineBase, "completions")
         if getattr(type(eng), "completions", base) is not base:
             end_us, comp = eng.completions(self, trace, batched=batched,
                                            segment_len=request.segment_len)
-            lat = _payload_latencies(lowered, comp, stream)
+            with _spans.span("extract"):
+                lat = _payload_latencies(lowered, comp, stream)
         else:   # makespan-only engines (log-depth forms)
             end_us = eng.end_time(self, trace, batched=batched,
                                   segment_len=request.segment_len)
@@ -1480,9 +1521,10 @@ class Simulator:
                 self, trace, self.kind, batched=batched,
                 segment_len=request.segment_len)
             energy = self._breakdown(sums, end_e, trace)
-        return self._result(trace, end_us, eng.caps.name, energy,
-                            request_lat_us=lat, sched_policy=policy_s,
-                            sampler=sampler)
+        with _spans.span("extract"):
+            return self._result(trace, end_us, eng.caps.name, energy,
+                                request_lat_us=lat, sched_policy=policy_s,
+                                sampler=sampler)
 
     def run_many(self, traces, *, policy: Policy | None = None,
                  objective: Objective = "end_time",
@@ -1763,19 +1805,26 @@ class Simulator:
         ``tables`` must be None (the FTL spec owns the 7-class table)
         and ``engine``/``segment_len``/``combine`` are ignored — the
         fused chain is the masked scan fold by construction."""
+        self._calls += 1
         if ftl is not None:
             if tables is not None:
                 raise ValueError(
                     "sweep(ftl=...) sweeps FTL design points — the "
                     "7-class table comes from the spec; tables must be "
                     "None")
-            return self._sweep_ftl(trace, ftl,
-                                   policy=policy or self.default_policy,
-                                   sched_policy=sched_policy, shard=shard)
-        return sweep_tables(
-            [self.table] if tables is None else tables, trace,
-            policy=policy or self.default_policy, engine=engine,
-            segment_len=segment_len, combine=combine, shard=shard)
+            ftl = list(ftl)
+            with _spans.span("sweep", call=self._calls,
+                             ops=trace.n_requests, points=len(ftl)):
+                return self._sweep_ftl(
+                    trace, ftl, policy=policy or self.default_policy,
+                    sched_policy=sched_policy, shard=shard)
+        tables = [self.table] if tables is None else list(tables)
+        with _spans.span("sweep", call=self._calls, ops=trace.n_ops,
+                         points=len(tables)):
+            return sweep_tables(
+                tables, trace, policy=policy or self.default_policy,
+                engine=engine, segment_len=segment_len, combine=combine,
+                shard=shard)
 
     def _sweep_ftl(self, stream: RequestStream, specs, *, policy: Policy,
                    sched_policy: str, shard: bool | None) -> np.ndarray:
@@ -2071,8 +2120,10 @@ def sweep_tables(tables, trace: OpTrace, *, policy: Policy = "eager",
             and eng.caps.jittable and eng.caps.batched_tables):
         fn = _sharded_batch_fn(mesh, engine, trace.channels, trace.ways,
                                batched, segment_len, combine)
-        return np.asarray(fn(*_stacked_table_args(tables),
-                             *_trace_args(trace)))
+        args = _staged_batch_args(tables, trace)
+        with _spans.span("fold", engine=engine, ops=trace.n_ops,
+                         steps=trace.n_ops, points=len(tables)):
+            return np.asarray(fn(*args))
     return eng.end_time_batch(tables, trace, batched=batched,
                               segment_len=segment_len, combine=combine)
 

@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans as _spans
 from repro.core.ftl import (ERASE, FTL_READ, FTL_WRITE, FTLSpec, FTLState,
                             FTLStats, FTLTranslation, GC_READ, GC_WRITE,
                             analytic_waf, precondition_lpns)
@@ -568,8 +569,9 @@ def _run_machine(fs: ScanFTLState, spec: FTLSpec, cls, arr, pay, rid,
                  lpns, t_hint: int):
     """Run the translation machine over one host-op batch, doubling the
     output buffer until the stream is fully consumed.  Returns
-    ``(final_state, ys)`` with ``ys`` the raw ``[t_max, 2*ppb+1]``
-    emission rows (``_trim`` flattens and masks them)."""
+    ``(final_state, ys, runs)`` with ``ys`` the raw ``[t_max, 2*ppb+1]``
+    emission rows (``_trim`` flattens and masks them) and ``runs`` the
+    launches of the fold (1 when ``t_hint`` held)."""
     n = len(cls)
     ppb = spec.pages_per_block
     n_b = _bucket(n + ppb)      # window slack: the ppb-op host slice
@@ -587,7 +589,9 @@ def _run_machine(fs: ScanFTLState, spec: FTLSpec, cls, arr, pay, rid,
     # never need more steps than this
     cap = 2 * _bucket(n * (4 * spec.blocks + 2) + 64)
     fs = fs._replace(h=_i32(0))
+    runs = 0
     while True:
+        runs += 1
         fold = _jitted_fold(spec.blocks, ppb, n_b, t_max)
         out, ys = fold(cls_p, arr_p, pay_p, rid_p, lpn_p, np.int32(n),
                        gc_free, is_lru, fs)
@@ -595,7 +599,7 @@ def _run_machine(fs: ScanFTLState, spec: FTLSpec, cls, arr, pay, rid,
         if err:
             _raise_scan_error(err, spec)
         if int(out.h) >= n and int(out.mode) == MODE_HOST:
-            return out, ys
+            return out, ys, runs
         if t_max >= cap:     # pragma: no cover - guard catches first
             raise RuntimeError(
                 "FTL scan translation failed to terminate "
@@ -643,31 +647,37 @@ def translate_scan(stream: RequestStream, spec: FTLSpec, *,
         raise ValueError(
             "FTL translation consumes host READ/WRITE streams only "
             f"(got op class {int(np.max(stream.op_cls))})")
-    if state is None:
-        fs = scan_state_fresh(spec)
-        if spec.precondition:
-            lp = precondition_lpns(spec)
-            npre = len(lp)
-            fs, _ = _run_machine(
-                fs, spec, np.full(npre, WRITE, np.int32),
-                np.zeros(npre, np.float32), np.zeros(npre, bool),
-                np.full(npre, -1, np.int32), lp,
-                estimate_t_max(spec, 0, npre, precondition=True))
-            fs = _reset_window(fs, spec.pages_per_block)
-    else:
-        fs = scan_state_from_host(state)
-    # the machine runs the state's own spec (a chained state owns the
-    # drive); the host-facing address space stays the caller's, exactly
-    # like the host path's request_lpns call
-    mspec = spec if state is None else state.spec
-    cls, arrival, rid, payload = request_ops(stream)
-    lpns = request_lpns(stream, spec.logical_pages)
-    n_writes = int(np.sum(cls == WRITE))
-    fs, ys = _run_machine(fs, mspec, cls, arrival, payload, rid, lpns,
-                          estimate_t_max(mspec, len(cls) - n_writes,
-                                         n_writes))
-    op_cls, arr, pay, rid_o, gc = _trim(ys)
-    out_state = scan_state_to_host(fs, mspec)
+    with _spans.span("translate") as sp:
+        runs = 0
+        if state is None:
+            fs = scan_state_fresh(spec)
+            if spec.precondition:
+                with _spans.span("precondition") as pre:
+                    lp = precondition_lpns(spec)
+                    npre = len(lp)
+                    fs, _, runs = _run_machine(
+                        fs, spec, np.full(npre, WRITE, np.int32),
+                        np.zeros(npre, np.float32), np.zeros(npre, bool),
+                        np.full(npre, -1, np.int32), lp,
+                        estimate_t_max(spec, 0, npre, precondition=True))
+                    fs = _reset_window(fs, spec.pages_per_block)
+                    pre.set_metadata(writes=npre, machine_runs=runs)
+        else:
+            fs = scan_state_from_host(state)
+        # the machine runs the state's own spec (a chained state owns
+        # the drive); the host-facing address space stays the caller's,
+        # exactly like the host path's request_lpns call
+        mspec = spec if state is None else state.spec
+        cls, arrival, rid, payload = request_ops(stream)
+        lpns = request_lpns(stream, spec.logical_pages)
+        n_writes = int(np.sum(cls == WRITE))
+        fs, ys, n_runs = _run_machine(
+            fs, mspec, cls, arrival, payload, rid, lpns,
+            estimate_t_max(mspec, len(cls) - n_writes, n_writes))
+        op_cls, arr, pay, rid_o, gc = _trim(ys)
+        out_state = scan_state_to_host(fs, mspec)
+        sp.set_metadata(host_ops=len(cls), out_ops=len(op_cls),
+                        machine_runs=runs + n_runs)
     return FTLTranslation(op_cls=op_cls, arrival_us=arr, payload=pay,
                           request_id=rid_o, gc=gc,
                           stats=out_state.stats, state=out_state)

@@ -136,8 +136,10 @@ def test_small_buffer_retry_converges():
     cls, arr, rid, pay = request_ops(stream)
     lpns = request_lpns(stream, spec.logical_pages)
     fs = ftl_scan.scan_state_from_host(ftl.FTLState(spec))
-    fs, ys = ftl_scan._run_machine(fs, spec, cls, arr, pay, rid, lpns, 1)
+    fs, ys, runs = ftl_scan._run_machine(fs, spec, cls, arr, pay, rid,
+                                         lpns, 1)
     assert int(np.sum(np.asarray(ys[-1]))) >= len(cls)
+    assert runs > 1, "a hint of one step must double at least once"
 
 
 # --- satellite: erase-count accounting --------------------------------------
