@@ -381,34 +381,50 @@ class _EngineBase:
 class ScanEngine(_EngineBase):
     """O(T) ``lax.scan`` fold (DESIGN.md §2.2) — the default engine.
     Session queries run the masked fold padded to length buckets, so
-    repeated nearby-length queries share one compile."""
+    repeated nearby-length queries share one compile.  On a TPU the
+    masked fold runs as one Pallas kernel (``repro.kernels.scanfold``,
+    bit-identical) that stops at the true op count; a ``lax.scan`` pays
+    ~20 µs of loop overhead a step there, while XLA:CPU runs it fast."""
 
-    def end_time(self, sim, trace, *, batched, segment_len):
-        t_b = _bucket_len(trace.n_ops)
-        fn = sim._closure(
-            ("scan", trace.channels, t_b, batched),
-            lambda: functools.partial(
-                _sim.trace_end_time_masked, *sim._targs,
-                n_channels=trace.channels, batched=batched))
-        with _spans.span("stage", steps=t_b):
-            args = _padded_trace_args(trace, t_b)
-        with _spans.span("fold", engine="scan", ops=trace.n_ops, steps=t_b,
-                         points=1):
-            return float(fn(*args))
+    def _masked_fold(self, sim, trace, *, batched, completions):
+        """(end_us, per-op completions or None) of the padded bucket."""
+        from repro.kernels.maxplus.kernel import resolve_interpret
+        from repro.kernels.scanfold import ops as _scanfold
 
-    def completions(self, sim, trace, *, batched, segment_len=None):
         t_b = _bucket_len(trace.n_ops)
+        kernel = not resolve_interpret(None)
+        if kernel:
+            fold = (_scanfold.scan_fold_completions if completions
+                    else _scanfold.scan_fold_end_time)
+        else:
+            fold = (_sim.trace_completions_masked if completions
+                    else _sim.trace_end_time_masked)
         fn = sim._closure(
-            ("scan-completions", trace.channels, t_b, batched),
+            ("scan-completions" if completions else "scan", trace.channels,
+             t_b, batched, kernel),
             lambda: functools.partial(
-                _sim.trace_completions_masked, *sim._targs,
-                n_channels=trace.channels, batched=batched))
+                fold, *sim._targs, n_channels=trace.channels,
+                batched=batched))
         with _spans.span("stage", steps=t_b):
-            args = _padded_trace_args(trace, t_b)
-        with _spans.span("fold", engine="scan", ops=trace.n_ops, steps=t_b,
-                         points=1):
+            padded = _pad_trace_np(trace, t_b)
+            # the kernel takes the op count where the scan takes the mask
+            tail = (np.int32(trace.n_ops),) if kernel else padded[-1:]
+            args = tuple(jnp.asarray(x) for x in padded[:-1] + tail)
+        with _spans.span("fold", engine="scan", ops=trace.n_ops,
+                         steps=trace.n_ops if kernel else t_b, points=1,
+                         kernel=int(kernel)):
+            if not completions:
+                return float(fn(*args)), None
             end, comp = fn(*args)
             return float(end), np.asarray(comp, np.float64)[: trace.n_ops]
+
+    def end_time(self, sim, trace, *, batched, segment_len):
+        return self._masked_fold(sim, trace, batched=batched,
+                                 completions=False)[0]
+
+    def completions(self, sim, trace, *, batched, segment_len=None):
+        return self._masked_fold(sim, trace, batched=batched,
+                                 completions=True)
 
     def dispatch_run(self, sim, cls, arrival_us, *, n_channels, n_ways,
                      rule, extra_us=None, retired=None):

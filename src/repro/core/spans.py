@@ -21,8 +21,11 @@ the calling thread:
     host arrays to device arguments at an engine call (``steps``);
 ``ssd.fold``
     one jitted engine call, from dispatch until its answer is on the
-    host (``engine``, ``ops``: real ops, ``steps``: the padded length,
-    ``points``);
+    host (``engine``, ``ops``: real ops, ``steps``: the steps the fold
+    runs, the padded length but for the kernel, ``points``; the scan
+    engine's ``end_time`` / ``completions`` also ``kernel``: 1 where
+    the Pallas fold of ``repro.kernels.scanfold`` ran, 0 for the
+    ``lax.scan``);
 ``ssd.extract``
     per-request latencies and the result's assembly.
 

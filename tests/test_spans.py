@@ -21,6 +21,7 @@ from repro.core.nand import CellType
 from repro.core.sim import SSDConfig
 from repro.core.spans import PREFIX
 from repro.core.workload import overwrite_stream, poisson_stream
+from repro.kernels.scanfold import ops as scanfold_ops
 
 SPEC = ftl.FTLSpec(blocks=32, pages_per_block=8, overprovision=0.25,
                    precondition=True)
@@ -146,6 +147,35 @@ def test_sweep_spans(tmp_path, engine):
     assert end.shape == (2,)
 
 
+@pytest.mark.parametrize("query", ["poisson", "aged"])
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_scan_folds_route_by_backend(tmp_path, monkeypatch, backend, query):
+    """On a TPU backend the scan engine's folds run the Pallas kernel
+    (interpreted here) over the true op count, and each ``ssd.fold``
+    says ``kernel=1``; elsewhere they run the masked ``lax.scan`` over
+    the bucket, ``kernel=0``.  The answers are identical."""
+    sim = _sim_for()
+    if query == "poisson":
+        stream, kw = poisson_stream(500, 5.0, read_fraction=0.7, seed=1), {}
+    else:
+        stream, kw = overwrite_stream(300, 150, seed=4), {"ftl": SPEC}
+    want = sim.run(stream, **kw)
+    if backend == "tpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(scanfold_ops, "resolve_interpret",
+                            lambda interpret: True)
+    res, spans = traced(tmp_path, lambda: sim.run(stream, **kw))
+    folds = named(spans, "ssd.fold")
+    assert len(folds) == (1 if query == "poisson" else 2)
+    on_tpu = backend == "tpu"
+    for f in folds:
+        assert f[3]["engine"] == "scan" and f[3]["kernel"] == int(on_tpu)
+        assert f[3]["steps"] == (f[3]["ops"] if on_tpu
+                                 else _bucket_len(f[3]["ops"]))
+    assert res.end_us == want.end_us
+    np.testing.assert_array_equal(res.request_lat_us, want.request_lat_us)
+
+
 def hlo_module(fn, *args, **kw) -> str:
     text = fn.lower(*args, **kw).compile().as_text()
     return text.split("HloModule ", 1)[1].split(",", 1)[0].split()[0]
@@ -163,6 +193,13 @@ def test_trace_readers_program_names_are_pinned():
     assert hlo_module(_sim.trace_end_time_masked, *sim._targs, *padded,
                       n_channels=2, batched=False) \
         == "jit_trace_end_time_masked"
+    n = jnp.int32(t.n_ops)
+    assert hlo_module(scanfold_ops.scan_fold_completions, *sim._targs,
+                      *padded[:-1], n, n_channels=2, batched=False) \
+        == "jit_scan_fold_completions"
+    assert hlo_module(scanfold_ops.scan_fold_end_time, *sim._targs,
+                      *padded[:-1], n, n_channels=2, batched=False) \
+        == "jit_scan_fold_end_time"
     stacked = tuple(x[None] for x in sim._targs)
     assert hlo_module(_sim.trace_end_time_prefix_batch, *stacked,
                       *_trace_args(t), n_channels=2, n_ways=4,

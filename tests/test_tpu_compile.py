@@ -1,8 +1,9 @@
-"""The (max,+) Pallas kernels compile for a TPU v5e chip.
+"""The Pallas kernels of the main path compile for a TPU v5e chip.
 
 Each test lowers and compiles one kernel ahead of time for a described
-``v5e:2x2`` topology (no chip attached) at the shapes ``chip_smoke.py``
-runs on the chip, with ``interpret=False``.  What the chip's compiler
+``v5e:2x2`` topology (no chip attached) with ``interpret=False``: the
+(max,+) kernels at the shapes ``chip_smoke.py`` runs on the chip, the
+scan engine's fold kernel at the benchmark cells' shapes.  What the chip's compiler
 refuses — an unsupported primitive, a misaligned reshape, a block over
 the scoped VMEM limit — fails here without chip time.
 """
@@ -16,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.maxplus.kernel import (maxplus_fold_kernel,
                                           maxplus_fold_many_kernel)
+from repro.kernels.scanfold import scan_fold_completions, scan_fold_end_time
 
 
 @pytest.fixture(scope="module")
@@ -60,6 +62,11 @@ PER_B, PER_M, PER_N, PER_T = 12, 16, 20, 4096
 IDX_B, IDX_M, IDX_N, IDX_T = 6, 64, 22, 16_384
 MANY_B, MANY_M1, MANY_N, MANY_T = 128, 65, 22, 4096
 PHASES = 4
+# the scan engine's fold kernel at the benchmark cells' shapes, 4
+# channels x 16 ways: 65,536 ops under the paper's 2-class table
+# (loaded_latency) and a 262,144-op bucket under the FTL's 7 classes
+# (the aged drive's fold)
+SCAN_CASES = {"paper_65536": (65_536, 2), "ftl_262144": (262_144, 7)}
 
 
 @pytest.mark.parametrize("energy", [False, True], ids=["end", "energy"])
@@ -106,6 +113,22 @@ def test_megakernel_compiles(one_chip, faults):
             mats, gvec, idx, arr, s0, lengths,
             extras=fw[0] if fw else None, wvec=fw[1] if fw else None,
             interpret=False)
+
+    _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("completions", [False, True],
+                         ids=["end", "completions"])
+@pytest.mark.parametrize("batched", [False, True], ids=["eager", "batched"])
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_scan_fold_kernel_compiles(one_chip, case, batched, completions):
+    t, k = SCAN_CASES[case]
+    shapes = ([((k,), F32)] * 7 + [((t,), I32)] * 4 + [((t,), F32)] * 2
+              + [((), I32)])
+    fold = scan_fold_completions if completions else scan_fold_end_time
+
+    def fn(*args):
+        return fold(*args, n_channels=4, batched=batched, interpret=False)
 
     _compile(fn, one_chip, *shapes)
 
