@@ -1,12 +1,14 @@
-"""The one traffic generator: a mix's parameters in, one query's input out.
+"""The traffic generators: a mix's parameters in, one query's input out.
 
 A traffic file under ``bench/traffic/`` names a ``generator`` and its
-parameters.  Each query's input is drawn from ``(seed, query index)``
-alone, so a run never simulates the same input twice and two runs of
-one seed simulate the same inputs.  Sizes never depend on the seed.
+parameters: one of ``GENERATORS`` below, else the ``generate(rng,
+params, cfg)`` of ``bench/generators/<generator>.py``.  Each query's
+input is drawn from ``(seed, query index)`` alone, so a run never
+simulates the same input twice and two runs of one seed simulate the
+same inputs.  Sizes never depend on the seed.
 
-Generators (vectorised copies of the program's builders, with the same
-semantics):
+Built-in generators (vectorised copies of the program's builders, with
+the same semantics):
 
 * ``mixed_trace`` -- ``n_ops`` page ops, each a read with probability
   ``read_fraction``; a drive of ``ways`` ways places them by
@@ -23,6 +25,8 @@ semantics):
 from __future__ import annotations
 
 import numpy as np
+
+import loader
 
 READ, WRITE = 0, 1
 
@@ -87,5 +91,12 @@ GENERATORS = {"mixed_trace": mixed_trace, "poisson_stream": poisson_stream,
 
 def query_input(traffic: dict, cfg: dict, seed: int, index: int) -> dict:
     """Input of query ``index`` of a run seeded ``seed``."""
-    gen = GENERATORS[traffic["generator"]]
-    return gen(rng_for(seed, index), traffic, cfg)
+    return generator(traffic["generator"])(rng_for(seed, index), traffic,
+                                           cfg)
+
+
+def generator(name: str):
+    """The generator of that name: built in, else a file of its own."""
+    if name in GENERATORS:
+        return GENERATORS[name]
+    return loader.load("generators", name).generate

@@ -3,10 +3,13 @@
 The program opens a ``jax.profiler.TraceAnnotation`` named ``ssd.<stage>``
 at each layer boundary of a query (``repro/core/spans.py`` lists them and
 their stats).  They sit on the host plane beside the harness's
-``bench.*`` spans, on the clock of the device programs.  This module
-keeps them, with their stats, and reduces them to the per-stage numbers
-below.  A trace of a program without such spans gives no spans, and
-every number here is then ``None``.
+``bench.*`` spans, on the clock of the device programs;
+``trace_reduce.read_xplane`` reads them with their stats, this module
+clips them to the traced window (``Reduced.program_spans``) and reduces
+them to the per-stage numbers below, each read by the per-layer metric
+of its name (``bench/metrics/<number>.py``).  A trace of a program
+without such spans gives no spans, and every number here is then
+``None``.
 
 A span is ``(stage, start_ns, end_ns, stats)``, the stage without the
 ``ssd.`` prefix.  Spans nest by containment on the calling thread.
@@ -27,9 +30,8 @@ from __future__ import annotations
 
 import re
 
-from trace_reduce import covered, gaps
+from trace_reduce import PROGRAM_PREFIX, covered
 
-PREFIX = "ssd."
 _DEVICE = re.compile(r"^/device:TPU:(\d+)$")
 
 
@@ -40,32 +42,23 @@ def from_events(host_events, window) -> list[tuple]:
     lo, hi = window
     out = []
     for n, s, d, stats in host_events:
-        if not n.startswith(PREFIX) or s + d <= lo or s >= hi:
+        if not n.startswith(PROGRAM_PREFIX) or s + d <= lo or s >= hi:
             continue
-        out.append((n[len(PREFIX):], max(s, lo), min(s + d, hi),
+        out.append((n[len(PROGRAM_PREFIX):], max(s, lo), min(s + d, hi),
                     dict(stats)))
     return sorted(out, key=lambda sp: (sp[1], -sp[2]))
 
 
-def read_xplane(path: str) -> tuple[list[tuple], dict[str, dict]]:
-    """From one read of an ``.xplane.pb`` file: [(name, start_ns,
-    dur_ns, stats)] of every ``ssd.`` event on its host planes, and the
-    events per line of each device plane (where a line holds fewer
-    programs than were run, the profiler dropped events)."""
+def device_lines(path: str) -> dict[str, dict]:
+    """The events per line of each device plane of an ``.xplane.pb``
+    file (where a line holds fewer programs than were run, the profiler
+    dropped events)."""
     from jax.profiler import ProfileData
 
-    spans, lines = [], {}
-    for plane in ProfileData.from_file(path).planes:
-        if _DEVICE.match(plane.name):
-            lines[plane.name] = {line.name: sum(1 for _ in line.events)
-                                 for line in plane.lines}
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                spans.extend((e.name, e.start_ns, e.duration_ns,
-                              dict(e.stats))
-                             for e in line.events
-                             if e.name.startswith(PREFIX))
-    return spans, lines
+    return {plane.name: {line.name: sum(1 for _ in line.events)
+                         for line in plane.lines}
+            for plane in ProfileData.from_file(path).planes
+            if _DEVICE.match(plane.name)}
 
 
 def stage_ms(spans, stage: str) -> float:
@@ -157,24 +150,3 @@ def fold_device_cover(spans, device_intervals) -> list[dict]:
                         "device_share": (covered(device_intervals, a, b)
                                          / (b - a) if b > a else 0.0)})
     return out
-
-
-def innermost_idle(r, spans, top: int = 12) -> list[list]:
-    """The traced window's idle time (no device ran a program), put down
-    to the innermost span open then: a program span (``ssd.<stage>``)
-    or a harness span (``query``, ``generate``), else ``harness`` (the
-    loop between spans).  ``r`` is the harness's ``trace_reduce.Reduced``.
-    Seconds, largest first."""
-    opened = ([("ssd." + n, a, b) for n, a, b, _ in spans]
-              + [(n, a, b) for n, a, b in r.spans if n != "window"])
-    idle: dict[str, float] = {}
-    for lo, hi in gaps(r.all_devices_intervals(), *r.window):
-        cuts = sorted({lo, hi} | {t for _, a, b in opened for t in (a, b)
-                                  if lo < t < hi})
-        for x, y in zip(cuts, cuts[1:]):
-            inside = [(a, -b, n) for n, a, b in opened if a <= x and y <= b]
-            name = max(inside)[2] if inside else "harness"
-            idle[name] = idle.get(name, 0.0) + (y - x) * 1e-9
-    return [[n, s] for n, s in sorted(idle.items(), key=lambda kv: -kv[1])
-            [:top]]
-

@@ -6,8 +6,30 @@
 configuration (``bench/configs/<config>.json``, via the entry's
 ``file``) and traffic mix (``bench/traffic/<traffic>.json``); each cell
 has its limits in ``bench/limits/<cell>.json`` and each per-layer metric
-its reader in ``bench/metrics/<metric>.py``.  Adding a cell, a
-configuration, a mix or a metric adds files and entries, and edits none.
+its reader in ``bench/metrics/<metric>.py``.  The harness finds every
+piece by its name (``bench/loader.py``).
+
+A cell adds files and entries, and edits no file that is there.  One
+with a new query shape adds:
+
+* a ``workloads`` entry, and a ``configs`` entry with its
+  ``bench/configs/<config>.json`` where the drive is new;
+* ``bench/traffic/<traffic>.json``, naming the mix's ``generator`` and
+  ``query`` kind and their parameters;
+* ``bench/generators/<generator>.py``, a function ``generate(rng,
+  params, cfg)``, where no built-in generator (``generate.GENERATORS``)
+  draws the mix;
+* ``bench/kinds/<query>.py``, a class ``Kind`` with ``__init__(cfg,
+  traffic)``, ``call(inp)``, ``work(inp)``, ``expect(inp,
+  dtype=np.float64)`` and ``readings(got, want)`` as the built-in kinds
+  (``system.KINDS``) have them, where none of those calls the query;
+  its plain reference may be a module of its own beside it;
+* ``bench/limits/<cell>.json``, a limit for each of ``readings``;
+* for each new per-layer metric, ``bench/metrics/<metric>.py`` with
+  ``read(r)`` (``r`` a ``trace_reduce.Reduced``: device programs,
+  harness spans and the program's ``ssd.*`` spans with their counters)
+  and a ``per_layer`` entry; the cell joins the ``workloads`` list of
+  each existing metric it reports.
 
 A run, in one process:
 
@@ -21,8 +43,9 @@ A run, in one process:
    ``TRACE_SECONDS`` (whole queries) and the per-layer metrics are read
    from it;
 4. the check: a sample of the window's queries, drawn from the seed, is
-   computed again by the plain reference (``bench/reference.py``) and
-   each compared number must stay within its limit.
+   computed again by the kind's plain reference (``bench/reference.py``
+   for the built-in kinds) and each compared number must stay within
+   its limit.
 
 The last line of standard output is the result, as one JSON object.
 """
@@ -35,7 +58,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -50,6 +72,8 @@ ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
 import generate  # noqa: E402
+import loader  # noqa: E402
+import program_spans  # noqa: E402
 import system  # noqa: E402
 import trace_reduce  # noqa: E402
 
@@ -65,7 +89,8 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 def load_cell(name: str) -> dict:
     """The cell's entry, configuration, traffic, limits and metrics."""
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench = loader.BENCH
+    spec = json.loads((bench.parent / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
         raise SystemExit(f"unknown workload {name!r} (one of "
@@ -74,11 +99,11 @@ def load_cell(name: str) -> dict:
     conf = {c["name"]: c for c in spec["configs"]}[cell["config"]]
     return {
         "cell": cell,
-        "config": json.loads((ROOT / conf["file"]).read_text()),
+        "config": json.loads((bench.parent / conf["file"]).read_text()),
         "traffic": json.loads(
-            (BENCH / "traffic" / f"{cell['traffic']}.json").read_text()),
+            (bench / "traffic" / f"{cell['traffic']}.json").read_text()),
         "limits": json.loads(
-            (BENCH / "limits" / f"{name}.json").read_text()),
+            (bench / "limits" / f"{name}.json").read_text()),
         "end_to_end": [m for m in spec["end_to_end"]
                        if name in m.get("workloads", [name])],
         "per_layer": [m for m in spec["per_layer"]
@@ -186,12 +211,31 @@ def check(kind, traffic, cfg, seed: int, answers, limits: dict
 
 
 def load_metric(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    """The reader of a per-layer metric."""
+    return loader.load("metrics", name).read
+
+
+def kind_class(query: str):
+    """The class of a query kind: built in, else a file of its own."""
+    if query in system.KINDS:
+        return system.KINDS[query]
+    return loader.load("kinds", query).Kind
+
+
+def reduce_events(device_events, host_events, program_events
+                  ) -> trace_reduce.Reduced:
+    """A traced window's events (as ``trace_reduce.read_xplane`` gives
+    them), reduced; the window runs from the first query's start to the
+    last query's end."""
+    queries = [(s, s + d) for n, s, d in host_events
+               if n == trace_reduce.SPAN_PREFIX + "query"]
+    if not queries:
+        raise RuntimeError("the trace holds no query span")
+    window = (min(a for a, _ in queries), max(b for _, b in queries))
+    r = trace_reduce.from_events(device_events, host_events, window)
+    r.program_spans = program_spans.from_events(program_events, window)
+    r.queries = len(queries)
+    return r
 
 
 class Tracer:
@@ -222,17 +266,10 @@ class Tracer:
             files = sorted(Path(self.dir).rglob("*.xplane.pb"))
             if not files:
                 raise RuntimeError("the profiler wrote no trace")
-            dev, host = trace_reduce.read_xplane(str(files[-1]))
+            events = trace_reduce.read_xplane(str(files[-1]))
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
-        queries = [(s, s + d) for n, s, d in host
-                   if n == trace_reduce.SPAN_PREFIX + "query"]
-        if not queries:
-            raise RuntimeError("the trace holds no query span")
-        window = (min(a for a, _ in queries), max(b for _, b in queries))
-        r = trace_reduce.from_events(dev, host, window)
-        r.queries = len(queries)
-        return r
+        return reduce_events(*events)
 
 
 def device_info(devices) -> dict:
@@ -250,7 +287,7 @@ def setup(name: str, chips_of=tpu_devices):
     sys.path.insert(0, str(ROOT / "src"))
     from repro.launch import compile_cache
     compile_cache.use_compile_cache()
-    kind = system.KINDS[c["traffic"]["query"]](c["config"], c["traffic"])
+    kind = kind_class(c["traffic"]["query"])(c["config"], c["traffic"])
     return c, kind, devices
 
 

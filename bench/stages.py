@@ -10,7 +10,7 @@ queries with the profiler on, reads the trace and prints one JSON
 object:
 
 * ``stages``: each number of ``program_spans.METRICS``;
-* ``harness``: the five per-layer metrics of ``BENCHMARK.json`` as the
+* ``harness``: the device-trace metrics of ``HARNESS_METRICS`` as the
   harness reads them from the same trace;
 * ``idle``: the window's device-idle time put down to the innermost
   open span, program or harness;
@@ -72,16 +72,10 @@ def traced_window(kind, c: dict, seed: int, seconds: float):
 
 def reduce(path, win) -> dict:
     """Everything this tool reports from one trace file."""
-    dev, host = trace_reduce.read_xplane(str(path))
-    queries = [(s, s + d) for n, s, d in host
-               if n == trace_reduce.SPAN_PREFIX + "query"]
-    window = (min(a for a, _ in queries), max(b for _, b in queries))
-    r = trace_reduce.from_events(dev, host, window)
-    r.queries = len(queries)
+    r = run.reduce_events(*trace_reduce.read_xplane(str(path)))
     r.point_ops = sum(win["work"][:r.queries])
-    events, lines = program_spans.read_xplane(str(path))
-    spans = program_spans.from_events(events, window)
-    cover = program_spans.query_coverage(spans, queries)
+    spans = r.program_spans
+    cover = program_spans.query_coverage(spans, r.query_spans())
     return {
         "queries": r.queries,
         "window_s": r.window_s,
@@ -89,12 +83,12 @@ def reduce(path, win) -> dict:
                    for name, f in program_spans.METRICS.items()},
         "harness": {name: run.load_metric(name)(r)
                     for name in HARNESS_METRICS},
-        "idle": program_spans.innermost_idle(r, spans),
+        "idle": trace_reduce.innermost_idle(r, top=12),
         "query_coverage": {"least": min(cover),
                            "mean": sum(cover) / len(cover)},
         "folds": program_spans.fold_device_cover(
             spans, r.all_devices_intervals())[:8],
-        "device_lines": lines,
+        "device_lines": program_spans.device_lines(str(path)),
         "device_ops": trace_reduce.breakdown(r)["device_ops"],
     }
 

@@ -147,7 +147,7 @@ def test_unbroken_run_is_correct(small_run, cell):
 
 @pytest.mark.parametrize("cell", sorted(SMALL))
 def test_control_is_not_correct(small_run, monkeypatch, cell):
-    kind = system.KINDS[run.load_cell(cell)["traffic"]["query"]]
+    kind = run.kind_class(run.load_cell(cell)["traffic"]["query"])
     monkeypatch.setattr(kind, "call", lambda self, inp: self.expect(
         inp, dtype=ml_dtypes.bfloat16))
     res = small_run(cell)

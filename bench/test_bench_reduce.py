@@ -9,6 +9,7 @@ index), whatever the seed's size.
 
 from __future__ import annotations
 
+import hashlib
 import json
 import sys
 from pathlib import Path
@@ -128,7 +129,7 @@ def recorded():
 
 def test_recorded_chip_trace_reads_as_on_the_chip():
     path, want = recorded()
-    dev, host = tr.read_xplane(str(path))
+    dev, host, _ = tr.read_xplane(str(path))
     assert list(dev) == [0], "one TPU plane"
     assert dev[0], "device programs on the XLA Modules line"
     queries = [(s, s + d) for n, s, d in host
@@ -177,6 +178,36 @@ def test_generator_repeats_per_seed_and_index(traffic):
     assert all(not np.array_equal(a[k], d[k]) for k in random)
     n = spec.get("n_ops", spec.get("n_requests"))
     assert all(len(a[k]) == n for k in arrays)
+
+
+#: sha256 over the inputs of three (seed, query index) pairs of each
+#: committed mix, drawn by the generators as they were before kinds and
+#: generators could be files of their own: the same inputs must follow.
+BUILTIN_DIGESTS = {
+    "mixed_65k":
+        "dd8b9a26b6490f2c883969242d06de9418eec934847f503922b455b35776d036",
+    "poisson_9us":
+        "eefb53c1acb46d93f682d46cb164a30ed418eb4c4f3bea660b19aab4f3dd298d",
+    "overwrite_burst_16k":
+        "f3052b156c401a37adacdcf97c7464b428ac6d9adaa2868dc474f1a7f06fc846",
+}
+
+
+@pytest.mark.parametrize("traffic", sorted(BUILTIN_DIGESTS))
+def test_builtin_generators_draw_as_before(traffic):
+    spec = json.loads((BENCH / "traffic" / f"{traffic}.json").read_text())
+    cfg_name = ("aged_ddr_slc_4x16" if spec["generator"] == "overwrite_stream"
+                else "paper_ddr_4x16")
+    cfg = json.loads((BENCH / "configs" / f"{cfg_name}.json").read_text())
+    h = hashlib.sha256()
+    for seed, index in [(2**31 + 12345, 3), (7, -1), (4294967311, 0)]:
+        inp = generate.query_input(spec, cfg, seed, index)
+        for k in sorted(inp):
+            a = np.asarray(inp[k])
+            h.update(k.encode())
+            h.update(str(a.dtype).encode())
+            h.update(a.tobytes())
+    assert h.hexdigest() == BUILTIN_DIGESTS[traffic]
 
 
 def test_mixed_trace_places_round_robin_with_mlc_parity():

@@ -102,11 +102,13 @@ def test_idle_goes_to_the_innermost_open_span():
     r = tr.from_events({0: [("jit_fold(1)", 3 * ms, ms),
                             ("jit_trace_completions_masked(2)", 7 * ms,
                              2 * ms)]}, host, (0, 12 * ms))
-    spans = ps.from_events(
+    plain = dict(tr.innermost_idle(r))
+    r.program_spans = ps.from_events(
         [("ssd.run", 2.5 * ms, 9 * ms, {}),
          ("ssd.translate", 2.5 * ms, 3 * ms, {}),
          ("ssd.fold", 6 * ms, 4 * ms, {})], r.window)
-    idle = dict(ps.innermost_idle(r, spans))
+    idle = dict(tr.innermost_idle(r))
+    assert tr.breakdown(r)["idle_gaps"] == tr.innermost_idle(r)
     # 0-2 generate; 2-2.5 query; 2.5-3 and 4-5.5 translate; 5.5-6 run;
     # 6-7 and 9-10 fold; 10-11.5 run; 11.5-12 query
     assert idle["generate"] == pytest.approx(2e-3)
@@ -117,10 +119,8 @@ def test_idle_goes_to_the_innermost_open_span():
     assert "harness" not in idle
     assert sum(idle.values()) == pytest.approx(
         r.window_s - r.busy_s(0))
-    # with no program spans the gaps go to the harness's spans, as
-    # trace_reduce.breakdown puts them
-    plain = dict(ps.innermost_idle(r, []))
-    assert plain == pytest.approx(dict(tr.breakdown(r)["idle_gaps"]))
+    # with no program spans the gaps go to the harness's spans
+    assert plain == pytest.approx({"generate": 2e-3, "query": 7e-3})
 
 
 def test_committed_chip_trace_reads_as_before():
@@ -129,7 +129,7 @@ def test_committed_chip_trace_reads_as_before():
     exactly the values its run printed."""
     path = TESTDATA / "design_sweep_1024ops.xplane.pb"
     want = json.loads((TESTDATA / "small_trace_result.json").read_text())
-    dev, host = tr.read_xplane(str(path))
+    dev, host, prog = tr.read_xplane(str(path))
     queries = [(s, s + d) for n, s, d in host
                if n == tr.SPAN_PREFIX + "query"]
     window = (min(a for a, _ in queries), max(b for _, b in queries))
@@ -140,9 +140,46 @@ def test_committed_chip_trace_reads_as_before():
     for name, m in want["metrics"].items():
         assert run.load_metric(name)(r) == m["value"], name
     assert run.load_metric("ftl_translate_ms_per_query")(r) is None
-    events, lines = ps.read_xplane(str(path))
-    spans = ps.from_events(events, window)
+    assert prog == []
+    spans = ps.from_events(prog, window)
     assert spans == []
+    lines = ps.device_lines(str(path))
     assert all(f(spans, r.queries) is None for f in ps.METRICS.values())
     assert lines["/device:TPU:0"]["XLA Modules"] == len(dev[0]) == 4
     assert lines["/device:TPU:0"]["XLA Ops"] == 6624
+
+
+def test_reduced_window_holds_the_program_spans():
+    """The harness's reduction clips the program's spans to the window
+    of whole queries; spans outside it are dropped."""
+    host = [("bench.window", 0, 60 * MS), ("bench.query", 0, 10 * MS),
+            ("bench.generate", 10 * MS, 10 * MS),
+            ("bench.query", 20 * MS, 10 * MS)]
+    prog = (aged_query(0) + aged_query(20 * MS)
+            + [("ssd.run", 40 * MS, 5 * MS, {"call": 3})])
+    r = run.reduce_events({0: [("jit_fold(7)", MS, MS)]}, host, prog)
+    assert r.window == (0, 30 * MS) and r.queries == 2
+    assert r.program_spans == ps.from_events(prog, r.window)
+    assert len(r.program_spans) == 22
+    assert r.program_spans[0] == ("run", 0, 10 * MS,
+                                  {"call": 1, "ops": 16, "points": 1})
+    # the idle time goes to the program's stages
+    idle = dict(tr.breakdown(r)["idle_gaps"])
+    assert idle["ssd.precondition"] == pytest.approx(2 * 3e-3 - 1e-3)
+    assert "query" not in idle
+
+
+def test_committed_chip_trace_has_no_program_spans():
+    r = run.reduce_events(*tr.read_xplane(
+        str(TESTDATA / "design_sweep_1024ops.xplane.pb")))
+    assert r.queries > 0 and r.program_spans == []
+    assert all(run.load_metric(name)(r) is None for name in ps.METRICS)
+
+
+@pytest.mark.parametrize("name", sorted(ps.METRICS))
+def test_span_metric_reads_as_program_spans(name):
+    r = tr.Reduced(programs={}, spans=[], window=(0, 30 * MS),
+                   program_spans=two_aged_queries(), queries=2)
+    got = run.load_metric(name)(r)
+    assert got is not None
+    assert got == ps.METRICS[name](r.program_spans, r.queries)

@@ -3,9 +3,11 @@
 The device planes (``/device:TPU:<n>``) carry one event per executed
 program on their ``XLA Modules`` line, named ``<jit name>(<hash>)``.
 The harness's own spans (``bench.query``, ``bench.generate``,
-``bench.window``) sit on a host plane.  Per-op events (the ``XLA Ops``
-line, one per loop iteration of a scan) are never read: programs are
-the unit every metric here counts.
+``bench.window``) sit on a host plane, and so do the program's own
+(``ssd.<stage>``, with their stats as counters; ``program_spans``
+reduces them).  Per-op events (the ``XLA Ops`` line, one per loop
+iteration of a scan) are never read: programs are the unit every
+metric here counts.
 
 ``Reduced`` is what a metric reader in ``bench/metrics/`` receives.
 Intervals are (start_ns, end_ns) on the profiler's clock.
@@ -17,6 +19,7 @@ import dataclasses
 import re
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "ssd."
 _HASH = re.compile(r"\(\d+\)$")
 
 
@@ -63,14 +66,20 @@ class Reduced:
     ``programs[d]`` lists (name, start_ns, end_ns) of device ``d``'s
     programs; ``spans`` lists (name, start_ns, end_ns) of the harness's
     spans, names without the ``bench.`` prefix; ``window`` is the traced
-    window (start_ns, end_ns).  ``queries`` and ``point_ops`` count the
-    whole queries the traced window ran and the work they asked for;
-    ``compiles`` is the harness's count of compilations in the window.
+    window (start_ns, end_ns).  ``program_spans`` lists (stage,
+    start_ns, end_ns, stats) of the program's ``ssd.<stage>`` spans,
+    clipped to the window (``program_spans.from_events``), outer first;
+    it is empty for a program without spans.  ``queries`` and
+    ``point_ops`` count the whole queries the traced window ran and the
+    work they asked for; ``compiles`` is the harness's count of
+    compilations in the window.
     """
 
     programs: dict[int, list[tuple[str, float, float]]]
     spans: list[tuple[str, float, float]]
     window: tuple[float, float]
+    program_spans: list[tuple[str, float, float, dict]] = \
+        dataclasses.field(default_factory=list)
     queries: int = 0
     point_ops: int = 0
     compiles: int = 0
@@ -112,12 +121,15 @@ _DEVICE = re.compile(r"^/device:TPU:(\d+)$")
 
 
 def read_xplane(path: str):
-    """(device_events, host_events) of an ``.xplane.pb`` file."""
+    """(device_events, host_events, program_events) of an
+    ``.xplane.pb`` file: the harness's host events as (name, start_ns,
+    dur_ns), the program's as (name, start_ns, dur_ns, stats)."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
     device_events: dict[int, list] = {}
     host_events: list = []
+    program_events: list = []
     for plane in data.planes:
         m = _DEVICE.match(plane.name)
         for line in plane.lines:
@@ -125,34 +137,44 @@ def read_xplane(path: str):
                 device_events.setdefault(int(m.group(1)), []).extend(
                     (e.name, e.start_ns, e.duration_ns) for e in line.events)
             elif not m and plane.name.startswith("/host:"):
-                host_events.extend(
-                    (e.name, e.start_ns, e.duration_ns) for e in line.events
-                    if e.name.startswith(SPAN_PREFIX))
-    return device_events, host_events
+                for e in line.events:
+                    if e.name.startswith(SPAN_PREFIX):
+                        host_events.append((e.name, e.start_ns,
+                                            e.duration_ns))
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        program_events.append((e.name, e.start_ns,
+                                               e.duration_ns, dict(e.stats)))
+    return device_events, host_events, program_events
+
+
+def innermost_idle(r: Reduced, top: int = 10) -> list[list]:
+    """The traced window's idle time (no device ran a program), put down
+    to the innermost span open then: a program span (``ssd.<stage>``)
+    or a harness span (``query``, ``generate``), else ``harness`` (the
+    loop between spans).  Seconds, largest first."""
+    opened = ([(PROGRAM_PREFIX + n, a, b) for n, a, b, _ in r.program_spans]
+              + [(n, a, b) for n, a, b in r.spans if n != "window"])
+    idle: dict[str, float] = {}
+    for lo, hi in gaps(r.all_devices_intervals(), *r.window):
+        cuts = sorted({lo, hi} | {t for _, a, b in opened for t in (a, b)
+                                  if lo < t < hi})
+        for x, y in zip(cuts, cuts[1:]):
+            inside = [(a, -b, n) for n, a, b in opened if a <= x and y <= b]
+            name = max(inside)[2] if inside else "harness"
+            idle[name] = idle.get(name, 0.0) + (y - x) * 1e-9
+    return [[n, s] for n, s in sorted(idle.items(), key=lambda kv: -kv[1])
+            [:top]]
 
 
 def breakdown(r: Reduced, top: int = 10) -> dict:
     """The device programs that took most time (seconds summed over
-    devices) and the time in the window when no device ran a program,
-    split by the harness span that was open (``harness`` where none
-    was: the loop between spans)."""
+    devices) and the window's idle time by the innermost open span
+    (``innermost_idle``)."""
     per: dict[str, float] = {}
     for d in r.programs:
         for name, a, b in r.programs[d]:
             t = max(0.0, min(b, r.window[1]) - max(a, r.window[0]))
             per[name] = per.get(name, 0.0) + t * 1e-9
     ops = sorted(per.items(), key=lambda kv: -kv[1])[:top]
-    idle: dict[str, float] = {}
-    spans = [(n, a, b) for n, a, b in r.spans if n != "window"]
-    for lo, hi in gaps(r.all_devices_intervals(), *r.window):
-        rest = hi - lo
-        for n, a, b in spans:
-            part = max(0.0, min(b, hi) - max(a, lo))
-            if part > 0:
-                idle[n] = idle.get(n, 0.0) + part * 1e-9
-                rest -= part
-        if rest > 0:
-            idle["harness"] = idle.get("harness", 0.0) + rest * 1e-9
-    gaps_ = sorted(idle.items(), key=lambda kv: -kv[1])[:top]
     return {"device_ops": [[n, s] for n, s in ops],
-            "idle_gaps": [[n, s] for n, s in gaps_]}
+            "idle_gaps": innermost_idle(r, top)}
