@@ -79,9 +79,10 @@ from repro.core.trace import (OpClassTable, OpTrace, READ, WRITE,
 from repro.core.workload import (RequestStream, aging_stream, build_workload,
                                  bursty_stream, checkpoint_requests,
                                  closed_loop_stream, datapipe_requests,
-                                 iter_request_chunks, kvoffload_requests,
-                                 multi_tenant, overwrite_stream,
-                                 poisson_stream, request_lpns, with_hedges)
+                                 iter_request_chunks, jesd219_stream,
+                                 kvoffload_requests, multi_tenant,
+                                 overwrite_stream, poisson_stream,
+                                 request_lpns, request_partial, with_hedges)
 
 __all__ = [
     # the session API proper
@@ -97,7 +98,7 @@ __all__ = [
     "checkpoint_requests", "closed_loop_stream", "datapipe_requests",
     "iter_request_chunks", "kvoffload_requests", "lower_static",
     "multi_tenant", "policy_is_dynamic", "poisson_stream", "aging_stream",
-    "overwrite_stream", "request_lpns",
+    "overwrite_stream", "request_lpns", "jesd219_stream", "request_partial",
     # the reliability layer (DESIGN.md §2.8)
     "FaultSampler", "FaultSpec", "apply_faults", "with_hedges",
     # the FTL stage (DESIGN.md §2.10-§2.11)

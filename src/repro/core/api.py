@@ -84,6 +84,7 @@ from repro.core.energy import (EnergyBreakdown, breakdown_from_sums,
                                op_phase_energy_uj)
 from repro.core.faults import FaultSampler, FaultSpec
 from repro.core.interface import InterfaceKind
+from repro.core.nand import chip as _nand_chip
 from repro.core.sched import LoweredWorkload
 from repro.core.sim import (MAX_WAYS, PageOpParams, Policy, SSDConfig,
                             policy_is_batched)
@@ -386,12 +387,13 @@ class ScanEngine(_EngineBase):
     bit-identical) that stops at the true op count; a ``lax.scan`` pays
     ~20 µs of loop overhead a step there, while XLA:CPU runs it fast."""
 
-    def _masked_fold(self, sim, trace, *, batched, completions):
-        """(end_us, per-op completions or None) of the padded bucket."""
+    def _masked_fold(self, sim, trace, *, batched, completions, steps=None):
+        """(end_us, per-op completions or None) of the padded bucket: the
+        bucket of ``trace.n_ops``, or of ``steps`` where that is more."""
         from repro.kernels.maxplus.kernel import resolve_interpret
         from repro.kernels.scanfold import ops as _scanfold
 
-        t_b = _bucket_len(trace.n_ops)
+        t_b = _bucket_len(max(trace.n_ops, steps or 0))
         kernel = not resolve_interpret(None)
         if kernel:
             fold = (_scanfold.scan_fold_completions if completions
@@ -418,13 +420,14 @@ class ScanEngine(_EngineBase):
             end, comp = fn(*args)
             return float(end), np.asarray(comp, np.float64)[: trace.n_ops]
 
-    def end_time(self, sim, trace, *, batched, segment_len):
+    def end_time(self, sim, trace, *, batched, segment_len, steps=None):
         return self._masked_fold(sim, trace, batched=batched,
-                                 completions=False)[0]
+                                 completions=False, steps=steps)[0]
 
-    def completions(self, sim, trace, *, batched, segment_len=None):
+    def completions(self, sim, trace, *, batched, segment_len=None,
+                    steps=None):
         return self._masked_fold(sim, trace, batched=batched,
-                                 completions=True)
+                                 completions=True, steps=steps)
 
     def dispatch_run(self, sim, cls, arrival_us, *, n_channels, n_ways,
                      rule, extra_us=None, retired=None):
@@ -1267,6 +1270,10 @@ class Simulator:
             self._ftl_sessions.move_to_end(key)
         return sess
 
+    def _page_bytes(self) -> int:
+        """User bytes of one flash page of the bound drive."""
+        return _nand_chip(self.config.cell).page_data_bytes
+
     def ftl_cache_info(self) -> CacheInfo:
         """Counters for the FTL sub-session cache — same shape as
         :meth:`cache_info`, but one entry here is a whole sibling
@@ -1314,7 +1321,8 @@ class Simulator:
             # default path: the compiled lax.scan translation engine
             # (DESIGN.md §2.11) — exact-agreement twin of the host
             # translator, regression-pinned op-for-op in the tests
-            translation = _ftl_scan.translate_scan(stream, spec)
+            translation = _ftl_scan.translate_scan(
+                stream, spec, page_bytes=self._page_bytes())
         else:
             # block-level program/erase failures draw RNG per attempt —
             # host-oracle territory (the scan folds stay RNG-free)
@@ -1322,7 +1330,7 @@ class Simulator:
                 stream, spec,
                 prog_fail_prob=fspec.prog_fail_prob,
                 erase_fail_prob=fspec.erase_fail_prob,
-                fault_seed=fspec.seed)
+                fault_seed=fspec.seed, page_bytes=self._page_bytes())
         extra = None
         sampler = None
         if fspec is not None:
@@ -1366,15 +1374,21 @@ class Simulator:
                     tr = dataclasses.replace(
                         tr, extra_us=np.asarray(ext, np.float32))
                 tr.validate_against(sess.table)
+            fold_kw = {}
+            if stream.sectored and isinstance(eng, ScanEngine):
+                # a sector stream's op counts move with its draw of sizes
+                # and RMW reads: 1/16 headroom keeps draws of one size in
+                # one bucket, so they share one compiled fold
+                fold_kw["steps"] = len(cls) + len(cls) // 16
             base = getattr(_EngineBase, "completions")
             if want_comp and getattr(type(eng), "completions",
                                      base) is not base:
                 end, comp = eng.completions(
                     sess, tr, batched=batched,
-                    segment_len=request.segment_len)
+                    segment_len=request.segment_len, **fold_kw)
                 return tr, end, comp
             end = eng.end_time(sess, tr, batched=batched,
-                               segment_len=request.segment_len)
+                               segment_len=request.segment_len, **fold_kw)
             return tr, end, None
 
         trace, end_us, comp = evaluate(want_comp=True)
@@ -1769,8 +1783,9 @@ class Simulator:
             for st in chunks:
                 if st.n_requests == 0:
                     continue
-                tr = _ftl_scan.translate_scan(st, spec,
-                                              state=carry["state"])
+                tr = _ftl_scan.translate_scan(
+                    st, spec, state=carry["state"],
+                    page_bytes=self._page_bytes())
                 carry["state"] = tr.state
                 carry["stats"] = tr.stats
                 ot, carry["off"] = _sched.lower_ops_chunk(
@@ -1894,6 +1909,11 @@ class Simulator:
         sess = self._ftl_session(specs[0])
         blocks, ppb = specs[0].blocks, specs[0].pages_per_block
         C, W = self.config.channels, self.config.ways
+        if stream.sectored:
+            raise ValueError(
+                "sweep(ftl=...) takes page-addressed streams; a "
+                "sector-addressed one runs through run(SimRequest(ftl=...)) "
+                "per point")
         cls, arr, rid, pay = _workload.request_ops(stream)
         if int(np.max(stream.op_cls)) > _trace.WRITE:
             raise ValueError(

@@ -36,6 +36,16 @@ This module is the stage between ``repro.core.workload`` and
   ``Garbage_Collector_Greedy``) and ``lru`` (coldest = oldest-opened
   block).
 
+Sector addressing (DESIGN.md §2.10): with ``FTLSpec.sectors_per_page``
+above 1, a sector-addressed stream (``RequestStream.first_sector`` /
+``n_sectors``) may cover a page only in part.  Writing such a page to a
+logical page that is already mapped is a read-modify-write: the
+translator emits one map-charged read of the old physical page
+(``FTL_READ``, no host payload, not GC, owned by its request) right
+before the program.  A partial write to an unmapped page reads nothing.
+The read → program data dependency is not modelled (the program may
+issue before the read completes), just as for GC's relocation pairs.
+
 Reliability integration (DESIGN.md §2.8): on the FTL path, program and
 erase failures retire *blocks* through the same valid/free accounting —
 a failed program wastes its frontier slot, re-programs at the next slot
@@ -65,7 +75,8 @@ from repro.core.interface import make_interface
 from repro.core.nand import chip as nand_chip
 from repro.core.sim import SSDConfig, controller_arb_us
 from repro.core.trace import READ, WRITE, OpClassTable, op_class_table
-from repro.core.workload import RequestStream, request_lpns, request_ops
+from repro.core.workload import (RequestStream, request_lpns, request_ops,
+                                 request_partial)
 
 #: Op-class indices of the FTL-extended table (rows 0/1 stay the plain
 #: host read/write of ``trace.op_class_table`` so non-FTL traces price
@@ -121,7 +132,10 @@ class FTLSpec:
     cell type's datasheet block-erase time (None = t_BERS).  With
     ``precondition`` the drive is silently filled and randomly
     overwritten ``precondition_passes`` logical passes before the
-    measured stream, so the measured window sits at steady state."""
+    measured stream, so the measured window sits at steady state.
+    ``sectors_per_page`` is the host's sectors per flash page: above 1,
+    sector-addressed streams read-modify-write the pages they cover in
+    part (module docstring)."""
 
     blocks: int = 128
     pages_per_block: int = 64
@@ -133,6 +147,7 @@ class FTLSpec:
     precondition: bool = False
     precondition_passes: float = 2.0
     seed: int = 0                    # preconditioning overwrite order
+    sectors_per_page: int = 1        # 1 = page-granular host addressing
 
     def __post_init__(self):
         if self.blocks < 4:
@@ -153,6 +168,9 @@ class FTLSpec:
             raise ValueError("erase_us must be >= 0")
         if self.precondition_passes < 0:
             raise ValueError("precondition_passes must be >= 0")
+        if self.sectors_per_page < 1:
+            raise ValueError(
+                f"sectors_per_page must be >= 1, got {self.sectors_per_page}")
         if self.gc_policy not in GC_POLICIES:
             raise ValueError(f"unknown GC policy {self.gc_policy!r} "
                              f"(one of {', '.join(GC_POLICIES)})")
@@ -275,6 +293,8 @@ class FTLStats:
     prog_fails: int = 0
     blocks_retired: int = 0
     free_page_low_watermark: int = 0
+    rmw_reads: int = 0               # reads of partly rewritten pages
+    host_bytes_written: int = 0      # sectors the host wrote, in bytes
     # per-block wear summary (ROADMAP wear leveling): computed from the
     # drive state's erase_count array when translate() returns, so it
     # covers the whole drive lifetime (preconditioning included) even
@@ -286,6 +306,14 @@ class FTLStats:
     def gc_op_count(self) -> int:
         """GC-injected trace ops (victim reads + remap writes + erases)."""
         return self.gc_reads + self.gc_writes + self.erases
+
+    def waf_bytes(self, page_bytes: int) -> float:
+        """Write amplification in bytes, as JESD218 states it: flash
+        bytes programmed over host bytes written (1.0 when the window
+        wrote nothing)."""
+        if self.host_bytes_written == 0:
+            return 1.0
+        return self.total_pages_written * page_bytes / self.host_bytes_written
 
     @property
     def waf(self) -> float:
@@ -496,17 +524,23 @@ def _gc_cycle(state: FTLState, emitter, arrival: float,
 
 
 def _run_ops(state: FTLState, emitter, cls, arrival, rid, payload, lpns,
-             rng, prog_fail_prob: float, erase_fail_prob: float):
+             rng, prog_fail_prob: float, erase_fail_prob: float,
+             partial=None):
     """Feed expanded host ops through the map, injecting GC on free-pool
     pressure.  GC ops inherit the triggering host op's arrival time, so
     the translated arrivals stay nondecreasing and the stream lowers
-    through the unmodified scheduler."""
+    through the unmodified scheduler.  A write flagged in ``partial``
+    to a mapped page reads the old copy first (read-modify-write)."""
     spec = state.spec
     for i in range(len(cls)):
         a = float(arrival[i])
         if cls[i] == READ:
             emitter.emit(FTL_READ, a, bool(payload[i]), int(rid[i]), False)
             continue
+        if (partial is not None and partial[i]
+                and state.l2p[int(lpns[i])] >= 0):
+            emitter.emit(FTL_READ, a, False, int(rid[i]), False)
+            state.stats.rmw_reads += 1
         state.stats.host_pages_written += 1
         _program(state, emitter, int(lpns[i]), a, bool(payload[i]),
                  int(rid[i]), FTL_WRITE, False, rng, prog_fail_prob)
@@ -552,14 +586,34 @@ def _precondition(state: FTLState, rng_faults, prog_fail_prob: float,
                            blocks_retired=retired)
 
 
+def host_write_bytes(stream: RequestStream, spec: FTLSpec,
+                     page_bytes: int | None) -> int:
+    """Bytes the stream's write requests ask the drive to store: their
+    sectors at ``page_bytes / sectors_per_page`` bytes each (0 when the
+    page size is not given)."""
+    if not page_bytes:
+        return 0
+    spp = spec.sectors_per_page
+    if page_bytes % spp:
+        raise ValueError(f"a {page_bytes}-byte page does not hold "
+                         f"{spp} whole sectors")
+    w = np.asarray(stream.op_cls) == WRITE
+    if stream.sectored:
+        sectors = int(np.sum(np.asarray(stream.n_sectors, np.int64)[w]))
+    else:
+        sectors = int(np.sum(np.asarray(stream.n_pages, np.int64)[w])) * spp
+    return sectors * (page_bytes // spp)
+
+
 def translate(stream: RequestStream, spec: FTLSpec, *,
               prog_fail_prob: float = 0.0, erase_fail_prob: float = 0.0,
-              fault_seed: int = 0,
-              state: FTLState | None = None) -> FTLTranslation:
+              fault_seed: int = 0, state: FTLState | None = None,
+              page_bytes: int | None = None) -> FTLTranslation:
     """Translate a host request stream into the physical op stream the
     drive executes (module docstring).  ``state`` chains aging across
     calls (None = a fresh drive, optionally preconditioned per the
-    spec).  Program/erase failure sampling uses a PCG64 stream keyed
+    spec).  ``page_bytes`` (the cell's data page) prices
+    ``FTLStats.host_bytes_written``.  Program/erase failure sampling uses a PCG64 stream keyed
     ``SeedSequence([fault_seed, 2])`` — disjoint from the FaultSampler's
     per-op (``[seed, 0]``) and retirement (``[seed, 1]``) streams, so
     the retry/jitter surcharges the query layer samples afterwards stay
@@ -579,9 +633,12 @@ def translate(stream: RequestStream, spec: FTLSpec, *,
                           erase_fail_prob)
     cls, arrival, rid, payload = request_ops(stream)
     lpns = request_lpns(stream, spec.logical_pages)
+    partial = request_partial(stream, spec.sectors_per_page)
+    state.stats.host_bytes_written += host_write_bytes(stream, spec,
+                                                       page_bytes)
     emitter = _Emitter()
     _run_ops(state, emitter, cls, arrival, rid, payload, lpns,
-             rng_faults, prog_fail_prob, erase_fail_prob)
+             rng_faults, prog_fail_prob, erase_fail_prob, partial)
     state.stats.max_erase_count = int(state.erase_count.max())
     state.stats.mean_erase_count = float(state.erase_count.mean())
     return FTLTranslation(
@@ -596,6 +653,7 @@ def translate(stream: RequestStream, spec: FTLSpec, *,
 __all__ = [
     "ERASE", "FTLSpec", "FTLState", "FTLStats", "FTLTranslation",
     "FTL_LABELS", "FTL_READ", "FTL_WRITE", "GC_POLICIES", "GC_READ",
-    "GC_WRITE", "analytic_waf", "ftl_op_class_table", "precondition_lpns",
+    "GC_WRITE", "analytic_waf", "ftl_op_class_table", "host_write_bytes",
+    "precondition_lpns",
     "select_victim", "translate",
 ]

@@ -41,6 +41,16 @@ the *same* translation:
   jaxpr joins the §2.9 invariant gates (RNG-free, f32 floats,
   primitive budget).
 
+Read-modify-write (sector-addressed streams, ``ftl`` module
+docstring) is decided before the machine runs: a logical page, once
+mapped, stays mapped (GC relocates it, nothing unmaps it), so a partial
+write reads the old copy exactly when its page was mapped in the
+machine's input state or written earlier in the stream
+(``rmw_mask``).  Each such read goes into the host stream as a
+payload-free read right before its write, so the machine — and its
+compiled program — is the page-granular one, and its burst emits the
+read in the lane before the write, as the host oracle orders them.
+
 Block-level fault injection (``prog_fail_prob`` / ``erase_fail_prob``)
 stays on the host path: its per-attempt RNG draws would put RNG
 primitives inside the fold, which the determinism contract forbids —
@@ -67,9 +77,11 @@ import numpy as np
 from repro.core import spans as _spans
 from repro.core.ftl import (ERASE, FTL_READ, FTL_WRITE, FTLSpec, FTLState,
                             FTLStats, FTLTranslation, GC_READ, GC_WRITE,
-                            analytic_waf, precondition_lpns)
+                            analytic_waf, host_write_bytes,
+                            precondition_lpns)
 from repro.core.trace import READ, WRITE
-from repro.core.workload import RequestStream, request_lpns, request_ops
+from repro.core.workload import (RequestStream, request_lpns, request_ops,
+                                 request_partial)
 
 #: ``mode`` register values (HOST bursts host ops; GC drains one whole
 #: relocation cycle per step until the trigger clears).
@@ -566,16 +578,17 @@ def _raise_scan_error(err: int, spec: FTLSpec):
 
 
 def _run_machine(fs: ScanFTLState, spec: FTLSpec, cls, arr, pay, rid,
-                 lpns, t_hint: int):
+                 lpns, t_hint: int, headroom: int = 0):
     """Run the translation machine over one host-op batch, doubling the
     output buffer until the stream is fully consumed.  Returns
     ``(final_state, ys, runs)`` with ``ys`` the raw ``[t_max, 2*ppb+1]``
     emission rows (``_trim`` flattens and masks them) and ``runs`` the
-    launches of the fold (1 when ``t_hint`` held)."""
+    launches of the fold (1 when ``t_hint`` held).  ``headroom`` extra
+    host slots go into the bucket the host arrays are padded to."""
     n = len(cls)
     ppb = spec.pages_per_block
-    n_b = _bucket(n + ppb)      # window slack: the ppb-op host slice
-    pad = n_b - n               # at h never clamps or misaligns
+    n_b = _bucket(n + ppb + headroom)   # window slack: the ppb-op host
+    pad = n_b - n                       # slice at h never clamps
     cls_p = np.pad(np.asarray(cls, np.int32), (0, pad))
     arr_p = np.pad(np.asarray(arr, np.float32), (0, pad))
     pay_p = np.pad(np.asarray(pay, bool), (0, pad))
@@ -631,8 +644,36 @@ def _reset_window(fs: ScanFTLState, ppb: int) -> ScanFTLState:
                        watermark=_i32(free_now), h=z)
 
 
+def rmw_mask(cls, lpns, partial, mapped) -> np.ndarray:
+    """[T] True at each write that reads its page's old copy first: a
+    write that covers its page in part (``partial``) to a logical page
+    that is ``mapped`` (indexed by lpn) before the stream or written
+    earlier in it."""
+    writes = np.flatnonzero(np.asarray(cls) == WRITE)
+    lp = np.asarray(lpns, np.int64)[writes]
+    _, first = np.unique(lp, return_index=True)
+    earlier = np.ones(len(writes), bool)
+    earlier[first] = False
+    out = np.zeros(len(cls), bool)
+    out[writes] = partial[writes] & (earlier | np.asarray(mapped)[lp])
+    return out
+
+
+def _with_rmw_reads(rmw, cls, arr, pay, rid, lpns):
+    """The host op arrays with a payload-free read of the same request
+    inserted before each ``rmw`` write."""
+    src = np.repeat(np.arange(len(cls)), 1 + rmw.astype(np.int64))
+    first = np.ones(len(src), bool)
+    first[1:] = src[1:] != src[:-1]
+    read = first & rmw[src]
+    return (np.where(read, READ, np.asarray(cls)[src]).astype(np.int32),
+            np.asarray(arr)[src], np.asarray(pay)[src] & ~read,
+            np.asarray(rid)[src], np.asarray(lpns)[src])
+
+
 def translate_scan(stream: RequestStream, spec: FTLSpec, *,
-                   state: FTLState | None = None) -> FTLTranslation:
+                   state: FTLState | None = None,
+                   page_bytes: int | None = None) -> FTLTranslation:
     """``ftl.translate`` compiled: identical op sequence, stats and
     final drive state for every fault-free translation, via the
     ``lax.scan`` machine instead of the per-op host loop.  ``state``
@@ -640,13 +681,15 @@ def translate_scan(stream: RequestStream, spec: FTLSpec, *,
     *not* mutated — use the returned ``FTLTranslation.state``.  Block-
     level fault probabilities are not accepted here (RNG stays outside
     the folds); ``repro.core.api`` routes faulty translations to the
-    host oracle."""
+    host oracle.  ``page_bytes`` prices ``FTLStats.host_bytes_written``
+    as in ``ftl.translate``."""
     if stream.n_requests == 0:
         raise ValueError("empty workload: no requests to translate")
     if int(np.max(stream.op_cls)) > WRITE:
         raise ValueError(
             "FTL translation consumes host READ/WRITE streams only "
             f"(got op class {int(np.max(stream.op_cls))})")
+    prev = FTLStats() if state is None else state.stats
     with _spans.span("translate") as sp:
         runs = 0
         if state is None:
@@ -670,14 +713,35 @@ def translate_scan(stream: RequestStream, spec: FTLSpec, *,
         mspec = spec if state is None else state.spec
         cls, arrival, rid, payload = request_ops(stream)
         lpns = request_lpns(stream, spec.logical_pages)
+        n_host = len(cls)
+        partial = request_partial(stream, spec.sectors_per_page) \
+            & (cls == WRITE)
+        n_rmw = 0
+        if partial.any():
+            mapped = np.asarray(fs.l2p) >= 0
+            rmw = rmw_mask(cls, lpns, partial, mapped)
+            n_rmw = int(rmw.sum())
+            cls, arrival, payload, rid, lpns = _with_rmw_reads(
+                rmw, cls, arrival, payload, rid, lpns)
         n_writes = int(np.sum(cls == WRITE))
+        # a sector stream's host-op count (pages plus RMW reads) moves
+        # with its draw of transfer sizes: 1/16 headroom keeps draws of
+        # one size on one compiled machine
         fs, ys, n_runs = _run_machine(
             fs, mspec, cls, arrival, payload, rid, lpns,
-            estimate_t_max(mspec, len(cls) - n_writes, n_writes))
+            estimate_t_max(mspec, len(cls) - n_writes, n_writes),
+            headroom=len(cls) // 16 if stream.sectored else 0)
         op_cls, arr, pay, rid_o, gc = _trim(ys)
         out_state = scan_state_to_host(fs, mspec)
-        sp.set_metadata(host_ops=len(cls), out_ops=len(op_cls),
-                        machine_runs=runs + n_runs)
+        out_state.stats.rmw_reads = prev.rmw_reads + n_rmw
+        out_state.stats.host_bytes_written = (
+            prev.host_bytes_written
+            + host_write_bytes(stream, spec, page_bytes))
+        sp.set_metadata(host_ops=n_host, out_ops=len(op_cls),
+                        machine_runs=runs + n_runs, rmw_reads=n_rmw,
+                        partial_pages=int(np.sum(partial)),
+                        host_sectors=stream.host_sectors(
+                            spec.sectors_per_page))
     return FTLTranslation(op_cls=op_cls, arrival_us=arr, payload=pay,
                           request_id=rid_o, gc=gc,
                           stats=out_state.stats, state=out_state)
@@ -687,7 +751,7 @@ __all__ = [
     "ERR_ALL_VALID", "ERR_GUARD", "ERR_NO_CAND", "ERR_NO_FREE",
     "MODE_GC", "MODE_HOST",
     "ScanFTLState", "estimate_ops", "estimate_t_max",
-    "make_translate_fold",
+    "make_translate_fold", "rmw_mask",
     "scan_state_fresh", "scan_state_from_host", "scan_state_to_host",
     "translate_scan",
 ]

@@ -17,7 +17,10 @@ on (Park et al. and the FMMU scalability argument, PAPERS.md):
 * :func:`closed_loop_stream` — a queue-depth-N client that admits request
   i when its model of request i-N completes (fio-style QD sweeps);
 * :func:`multi_tenant`     — merge streams into one arrival-ordered
-  multi-tenant workload, preserving per-stream ids.
+  multi-tenant workload, preserving per-stream ids;
+* :func:`jesd219_stream`   — JEDEC JESD219A's enterprise endurance
+  workload: sector-addressed random writes of 0.5-64 KiB under the
+  standard's 5/15/80% LBA access skew.
 
 The storage tier emits its workloads here (``checkpoint_requests`` /
 ``datapipe_requests`` / ``kvoffload_requests``); their static-stripe
@@ -63,6 +66,8 @@ class RequestStream:
     payload: np.ndarray | None = None   # bool [R]; None = all payload
     hedge_of: np.ndarray | None = None  # int32 [R]; -1 = not a hedge
     lpn: np.ndarray | None = None       # int64 [R]; None = address-free
+    first_sector: np.ndarray | None = None  # int32 [R]; None = whole pages
+    n_sectors: np.ndarray | None = None     # int32 [R], >= 1
 
     def __post_init__(self):
         r = len(self.arrival_us)
@@ -71,12 +76,21 @@ class RequestStream:
                 raise ValueError(f"RequestStream.{name} has length "
                                  f"{len(getattr(self, name))}, "
                                  f"arrival_us has {r}")
-        for name in ("payload", "hedge_of", "lpn"):
+        for name in ("payload", "hedge_of", "lpn", "first_sector",
+                     "n_sectors"):
             arr = getattr(self, name)
             if arr is not None and len(arr) != r:
                 raise ValueError(f"RequestStream.{name} length mismatch")
+        if (self.first_sector is None) != (self.n_sectors is None):
+            raise ValueError("first_sector and n_sectors come together "
+                             "(a sector-addressed stream has both)")
         if r == 0:
             return
+        if self.sectored:
+            if int(np.min(self.first_sector)) < 0:
+                raise ValueError("first_sector must be non-negative")
+            if int(np.min(self.n_sectors)) < 1:
+                raise ValueError("n_sectors must be >= 1")
         if self.lpn is not None and int(np.min(self.lpn)) < 0:
             raise ValueError("lpn must be non-negative")
         if float(np.min(self.arrival_us)) < 0:
@@ -101,6 +115,35 @@ class RequestStream:
                     raise ValueError(
                         "a hedge duplicate must match its primary's "
                         "n_pages (it mirrors the primary op-for-op)")
+
+    @property
+    def sectored(self) -> bool:
+        """True for a sector-addressed stream (``first_sector`` set)."""
+        return self.first_sector is not None
+
+    def check_sectors(self, sectors_per_page: int) -> None:
+        """Raise unless every request's sector range starts inside its
+        first page and spans exactly its ``n_pages`` pages."""
+        if not self.sectored or self.n_requests == 0:
+            return
+        first = np.asarray(self.first_sector, np.int64)
+        end = first + np.asarray(self.n_sectors, np.int64)
+        if int(np.max(first)) >= sectors_per_page:
+            raise ValueError(
+                f"first_sector must be < sectors_per_page "
+                f"({sectors_per_page}): it is the offset in the first page")
+        if np.any(-(-end // sectors_per_page)
+                  != np.asarray(self.n_pages, np.int64)):
+            raise ValueError(
+                "n_pages must equal ceil((first_sector + n_sectors) / "
+                f"sectors_per_page) at {sectors_per_page} sectors a page")
+
+    def host_sectors(self, sectors_per_page: int) -> int:
+        """Sectors the requests ask for (whole pages count
+        ``sectors_per_page`` each)."""
+        if not self.sectored:
+            return self.total_pages * sectors_per_page
+        return int(np.sum(np.asarray(self.n_sectors, np.int64)))
 
     def hedge_mask(self) -> np.ndarray:
         """[R] True where the request is a linked hedge duplicate."""
@@ -131,7 +174,7 @@ class RequestStream:
 
 
 def _stream(arrival, op_cls, n_pages, stream, payload=None,
-            lpn=None) -> RequestStream:
+            lpn=None, first_sector=None, n_sectors=None) -> RequestStream:
     r = len(arrival)
     return RequestStream(
         arrival_us=np.asarray(arrival, np.float32),
@@ -141,7 +184,11 @@ def _stream(arrival, op_cls, n_pages, stream, payload=None,
         stream=(np.full(r, stream, np.int32)
                 if np.isscalar(stream) else np.asarray(stream, np.int32)),
         payload=None if payload is None else np.asarray(payload, bool),
-        lpn=None if lpn is None else np.asarray(lpn, np.int64))
+        lpn=None if lpn is None else np.asarray(lpn, np.int64),
+        first_sector=(None if first_sector is None
+                      else np.asarray(first_sector, np.int32)),
+        n_sectors=None if n_sectors is None else np.asarray(n_sectors,
+                                                            np.int32))
 
 
 def _classes(n: int, read_fraction: float, rng) -> np.ndarray:
@@ -239,6 +286,10 @@ def multi_tenant(streams) -> RequestStream:
         raise ValueError(
             "cannot merge streams with and without logical addresses "
             "(lpn): give every tenant an lpn array or none")
+    sectored = [s.sectored for s in streams]
+    if any(sectored) and not all(sectored):
+        raise ValueError(
+            "cannot merge sector-addressed and page-addressed streams")
     return RequestStream(
         arrival_us=np.asarray(arrival, np.float32)[order],
         op_cls=cat([s.op_cls for s in streams]),
@@ -248,7 +299,11 @@ def multi_tenant(streams) -> RequestStream:
         payload=(None if all(s.payload is None for s in streams)
                  else cat([s.payload_mask() for s in streams])),
         hedge_of=hedge_of,
-        lpn=None if not all(with_lpn) else cat([s.lpn for s in streams]))
+        lpn=None if not all(with_lpn) else cat([s.lpn for s in streams]),
+        first_sector=(cat([s.first_sector for s in streams])
+                      if all(sectored) else None),
+        n_sectors=(cat([s.n_sectors for s in streams])
+                   if all(sectored) else None))
 
 
 def with_hedges(stream: RequestStream, fraction: float,
@@ -299,7 +354,11 @@ def with_hedges(stream: RequestStream, fraction: float,
         hedge_of=np.where(h_s >= 0, inv[np.clip(h_s, 0, None)],
                           -1).astype(np.int32),
         lpn=(None if stream.lpn is None
-             else np.asarray(stream.lpn, np.int64)[src][order]))
+             else np.asarray(stream.lpn, np.int64)[src][order]),
+        first_sector=(None if not stream.sectored else np.asarray(
+            stream.first_sector, np.int32)[src][order]),
+        n_sectors=(None if not stream.sectored else np.asarray(
+            stream.n_sectors, np.int32)[src][order]))
 
 
 def request_ops(stream: RequestStream
@@ -333,6 +392,26 @@ def request_lpns(stream: RequestStream, n_logical: int) -> np.ndarray:
     pos = t - np.repeat(starts, reps)          # op offset within request
     return (np.repeat(np.asarray(stream.lpn, np.int64), reps)
             + pos) % n_logical
+
+
+def request_partial(stream: RequestStream,
+                    sectors_per_page: int) -> np.ndarray:
+    """Per-page-op flags [T = total_pages], True where the request
+    covers its page only in part: the head page of a request that
+    starts past the page's first sector, and the tail page of one that
+    ends before the page's last.  A write to such a page is a
+    read-modify-write at the FTL.  Whole-page streams flag nothing."""
+    if not stream.sectored:
+        return np.zeros(stream.total_pages, bool)
+    stream.check_sectors(sectors_per_page)
+    reps = np.asarray(stream.n_pages, np.int64)
+    first = np.asarray(stream.first_sector, np.int64)
+    end = first + np.asarray(stream.n_sectors, np.int64)
+    t = np.arange(int(reps.sum()), dtype=np.int64)
+    pos = t - np.repeat(np.cumsum(reps) - reps, reps)
+    return (((pos == 0) & np.repeat(first > 0, reps))
+            | ((pos == np.repeat(reps - 1, reps))
+               & np.repeat(end % sectors_per_page != 0, reps)))
 
 
 def iter_request_chunks(stream: RequestStream, chunk_requests: int):
@@ -442,6 +521,56 @@ def aging_stream(n_requests: int, footprint_pages: int, *,
                    pages_per_request, stream, lpn=lpn)
 
 
+#: JEDEC JESD219A enterprise endurance workload, transfer sizes: (KiB,
+#: share of requests).
+JESD219_ENTERPRISE_SIZES_KIB: tuple[tuple[float, float], ...] = (
+    (0.5, 0.04), (1.0, 0.01), (1.5, 0.01), (2.0, 0.01), (2.5, 0.01),
+    (3.0, 0.01), (3.5, 0.01), (4.0, 0.67), (8.0, 0.10), (16.0, 0.07),
+    (32.0, 0.03), (64.0, 0.03))
+
+#: JESD219A enterprise access skew: (share of the user LBA space, share
+#: of accesses), regions counted from LBA 0.
+JESD219_ENTERPRISE_SKEW: tuple[tuple[float, float], ...] = (
+    (0.05, 0.50), (0.15, 0.30), (0.80, 0.20))
+
+#: JESD219A's logical sector, and the alignment of transfers of 4 KiB
+#: and more, in such sectors.
+SECTOR_BYTES, JESD219_ALIGN_SECTORS = 512, 8
+
+
+def jesd219_stream(n_requests: int, logical_pages: int,
+                   sectors_per_page: int, seed: int = 0) -> RequestStream:
+    """JESD219A's enterprise endurance workload as a sector-addressed
+    write stream over ``logical_pages`` pages of ``sectors_per_page``
+    512-byte sectors, all arriving at t = 0 (a saturating burst):
+    transfer sizes from ``JESD219_ENTERPRISE_SIZES_KIB``; transfers of
+    4 KiB or more aligned to 4 KiB, smaller ones at any sector; each
+    access in a region of ``JESD219_ENTERPRISE_SKEW``, uniform within
+    it.  A transfer that would pass the end of the LBA space starts
+    early enough to fit."""
+    kib, p_size = np.asarray(JESD219_ENTERPRISE_SIZES_KIB).T
+    lba_share, p_region = np.asarray(JESD219_ENTERPRISE_SKEW).T
+    lba = int(logical_pages) * int(sectors_per_page)
+    n_sec_of = np.rint(kib * 1024 / SECTOR_BYTES).astype(np.int64)
+    if lba < int(n_sec_of.max()) + JESD219_ALIGN_SECTORS:
+        raise ValueError(f"the LBA space ({lba} sectors) cannot hold a "
+                         f"{kib.max():g} KiB transfer")
+    rng = np.random.default_rng(seed)
+    n_sec = n_sec_of[rng.choice(len(kib), n_requests, p=p_size)]
+    edges = np.rint(np.cumsum(np.r_[0.0, lba_share]) * lba).astype(np.int64)
+    region = rng.choice(len(p_region), n_requests, p=p_region)
+    step = np.where(n_sec >= JESD219_ALIGN_SECTORS, JESD219_ALIGN_SECTORS, 1)
+    lo = -(-edges[region] // step)
+    hi = np.maximum(edges[region + 1] // step, lo + 1)
+    start = np.minimum(rng.integers(lo, hi) * step,
+                       (lba - n_sec) // step * step)
+    first = start % sectors_per_page
+    return _stream(np.zeros(n_requests), np.full(n_requests, WRITE),
+                   -(-(first + n_sec) // sectors_per_page), 0,
+                   lpn=start // sectors_per_page, first_sector=first,
+                   n_sectors=n_sec)
+
+
 # ---------------------------------------------------------------------------
 # Storage-tier request emitters (stripe-lowered twins of the retired
 # trace builders; regression-pinned numerically identical)
@@ -528,7 +657,7 @@ WORKLOAD_KINDS: tuple[str, ...] = (
     "steady_read", "steady_write", "mixed", "hot_cold",
     "checkpoint", "datapipe", "kvoffload",
     "poisson", "bursty", "closed_loop",
-    "overwrite", "aging",
+    "overwrite", "aging", "jesd219",
 )
 
 _BUILDERS = {
@@ -563,6 +692,11 @@ _BUILDERS = {
     "aging": _lowered(
         lambda cfg, n_requests=512, footprint_pages=2048, **kw:
         aging_stream(n_requests, footprint_pages, **kw)),
+    "jesd219": _lowered(
+        lambda cfg, n_requests=512, logical_pages=2048, **kw:
+        jesd219_stream(n_requests, logical_pages,
+                       nand_chip(cfg.cell).page_data_bytes // SECTOR_BYTES,
+                       **kw)),
 }
 
 

@@ -5,6 +5,7 @@ spans nested as the query path opens them, with their stats, and the
 program names that trace readers match must stay what they are.
 """
 
+import dataclasses
 import glob
 
 import jax
@@ -20,7 +21,8 @@ from repro.core.api import _bucket_len, _padded_trace_args, _trace_args
 from repro.core.nand import CellType
 from repro.core.sim import SSDConfig
 from repro.core.spans import PREFIX
-from repro.core.workload import overwrite_stream, poisson_stream
+from repro.core.workload import (jesd219_stream, overwrite_stream,
+                                 poisson_stream, request_partial)
 from repro.kernels.scanfold import ops as scanfold_ops
 
 SPEC = ftl.FTLSpec(blocks=32, pages_per_block=8, overprovision=0.25,
@@ -109,6 +111,24 @@ def test_short_estimate_counts_every_machine_run(tmp_path, monkeypatch):
     assert pre[3]["machine_runs"] > 1
     assert trans[3]["machine_runs"] > pre[3]["machine_runs"] + 1
     assert res.end_us == want.end_us
+
+
+def test_sector_stream_translate_span_carries_rmw_counters(tmp_path):
+    """A sector-addressed stream's ``ssd.translate`` span carries the
+    RMW reads, partial pages and host sectors, equal to ``FTLStats``."""
+    sim = _sim_for()
+    spec = dataclasses.replace(SPEC, sectors_per_page=4)
+    stream = jesd219_stream(120, spec.logical_pages, 4, seed=6)
+    sim.run(stream, ftl=spec)
+    res, spans = traced(tmp_path, lambda: sim.run(stream, ftl=spec))
+    (trans,) = named(spans, "ssd.translate")
+    st = trans[3]
+    assert st["rmw_reads"] == res.ftl_stats.rmw_reads > 0
+    assert st["host_sectors"] * 512 == res.ftl_stats.host_bytes_written
+    assert st["host_sectors"] == int(np.sum(stream.n_sectors))
+    assert st["partial_pages"] == int(request_partial(stream, 4).sum())
+    assert st["host_ops"] == stream.total_pages
+    assert st["out_ops"] == res.n_ops
 
 
 def test_workload_run_spans(tmp_path):

@@ -65,8 +65,10 @@ PHASES = 4
 # the scan engine's fold kernel at the benchmark cells' shapes, 4
 # channels x 16 ways: 65,536 ops under the paper's 2-class table
 # (loaded_latency) and a 262,144-op bucket under the FTL's 7 classes
-# (the aged drive's fold)
-SCAN_CASES = {"paper_65536": (65_536, 2), "ftl_262144": (262_144, 7)}
+# (the aged drive's fold), and the 2^23-op bucket of JESD219A's enterprise
+# workload on the aged drive (read-modify-write, GC and all)
+SCAN_CASES = {"paper_65536": (65_536, 2), "ftl_262144": (262_144, 7),
+              "ftl_8388608": (8_388_608, 7)}
 
 
 @pytest.mark.parametrize("energy", [False, True], ids=["end", "energy"])
