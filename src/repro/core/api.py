@@ -1367,7 +1367,7 @@ class Simulator:
                     extra_us=(None if ext is None
                               else np.asarray(ext, np.float32)))
                 return tr, end, comp
-            with _spans.span("lower", ops=len(cls)):
+            with _spans.span("lower", ops=len(cls), ranked_ops=0):
                 tr = _sched.lower_ops(cls, arr, channels, ways, policy_s,
                                       payload=pay)
                 if ext is not None:
@@ -1524,8 +1524,9 @@ class Simulator:
                 return self._result(trace, end, eng.caps.name, energy,
                                     request_lat_us=lat,
                                     sched_policy=policy_s, sampler=sampler)
-        with _spans.span("lower", ops=stream.n_requests):
+        with _spans.span("lower", ops=stream.n_requests) as sp:
             lowered = _sched.lower_static(stream, channels, ways, policy_s)
+            sp.set_metadata(ranked_ops=lowered.ranked_ops)
             trace = lowered.trace
             sampler = None
             if spec is not None:

@@ -49,7 +49,7 @@ import dataclasses
 import numpy as np
 
 from repro.core.faults import FaultSampler, FaultSpec
-from repro.core.trace import OpTrace, _finalize
+from repro.core.trace import OpTrace, _check_geometry, _finalize
 from repro.core.workload import RequestStream, request_ops
 
 STATIC_POLICIES: tuple[str, ...] = ("stripe", "round_robin")
@@ -74,11 +74,14 @@ class LoweredWorkload:
     """A request stream lowered onto a geometry: the placed ``OpTrace``
     plus the op→request map latency accounting needs.  ``trace`` keeps
     ``arrival_us=None`` when every arrival is zero, so zero-arrival
-    lowerings are field-for-field identical to the retired builders."""
+    lowerings are field-for-field identical to the retired builders.
+    ``ranked_ops`` counts the ops whose page parity took
+    ``_finalize``'s per-chip rank rather than the closed form."""
 
     trace: OpTrace
     request_id: np.ndarray          # int32 [T] op -> request index
     request_arrival_us: np.ndarray  # float32 [R]
+    ranked_ops: int = 0
 
     def request_latencies(self, completion_us) -> np.ndarray:
         """[R] request latency: last page-op completion − arrival, for
@@ -91,94 +94,93 @@ class LoweredWorkload:
         return done - np.asarray(self.request_arrival_us, np.float64)
 
 
-def lower_static(stream: RequestStream, channels: int, ways: int,
-                 policy: str = "stripe") -> LoweredWorkload:
-    """Lower a request stream to a placed ``OpTrace`` under a static
-    policy (see module docstring).  Placement slots advance over
-    *payload* ops only; non-payload (hedged duplicate) ops copy their
-    primary's placement shifted one channel."""
+def _require_static(policy: str) -> None:
     if policy_is_dynamic(policy):
         raise ValueError(
             f"sched policy {policy!r} is dynamic — it cannot be lowered "
             "offline; run it through Simulator.run(workload=...) / "
             "sim.dispatch_trace (engines with the 'dispatch' capability)")
-    cls, arrival, req_id, payload = request_ops(stream)
-    slots = np.cumsum(payload) - 1                  # payload-op slot index
+
+
+def _place(slots: np.ndarray, channels: int, ways: int, policy: str
+           ) -> tuple[np.ndarray, np.ndarray]:
+    """(channel, way) of each placement slot under a static policy."""
     if policy == "stripe":
-        chan = slots % channels
-        way = (slots // channels) % ways
-    else:                                           # "round_robin": way-first
-        way = slots % ways
-        chan = (slots // ways) % channels
-    if not payload.all():
-        hof = (np.full(stream.n_requests, -1, np.int64)
-               if stream.hedge_of is None
-               else np.asarray(stream.hedge_of, np.int64))
-        h = hof[req_id]                             # primary request per op
-        is_h = h >= 0
-        # duplicates without an explicit primary link: legacy adjacency
-        # rule (their stagnant slot is the preceding payload op's)
-        chan = np.where(~payload & ~is_h, (chan + 1) % channels, chan)
-        if is_h.any():
-            # hedge_of-linked duplicates mirror op j of their primary
-            # request shifted one channel AND one way.  The channel
-            # shift is the replica-read rule; the way shift keeps the
-            # duplicate off the chip the stripe is about to reuse for
-            # the *next* payload op — without it every duplicate queues
-            # on exactly that chip and (FCFS issue being serial through
-            # the controller) convoys the whole stream, inverting the
-            # mitigation it exists to provide.
-            reps = np.asarray(stream.n_pages, np.int64)
-            starts = np.cumsum(reps) - reps         # [R] first-op index
-            pos = np.arange(len(cls)) - starts[req_id]
-            src = starts[np.clip(h, 0, None)] + pos
-            chan = np.where(is_h, (chan[src] + 1) % channels, chan)
-            way = np.where(is_h, (way[src] + 1) % ways, way)
-    # _finalize owns the MLC per-chip page-parity derivation (the one
-    # definition every trace builder shares); arrivals ride on top
+        return slots % channels, (slots // channels) % ways
+    # "round_robin": way-first
+    return (slots // ways) % channels, slots % ways
+
+
+def lower_static(stream: RequestStream, channels: int, ways: int,
+                 policy: str = "stripe") -> LoweredWorkload:
+    """Lower a request stream to a placed ``OpTrace`` under a static
+    policy (see module docstring).  Placement slots advance over
+    *payload* ops only; non-payload (hedged duplicate) ops copy their
+    primary's placement shifted one channel.
+
+    Without duplicates every op advances a slot, so the lowering is
+    :func:`lower_ops`'s, parity in closed form; with them the parity
+    is ``_finalize``'s per-chip rank."""
+    _require_static(policy)
+    cls, arrival, req_id, payload = request_ops(stream)
+    arrival_out = np.asarray(stream.arrival_us, np.float32)
+    if payload.all():
+        return LoweredWorkload(
+            trace=lower_ops(cls, arrival, channels, ways, policy),
+            request_id=req_id, request_arrival_us=arrival_out)
+    slots = np.cumsum(payload) - 1                  # payload-op slot index
+    chan, way = _place(slots, channels, ways, policy)
+    hof = (np.full(stream.n_requests, -1, np.int64)
+           if stream.hedge_of is None
+           else np.asarray(stream.hedge_of, np.int64))
+    h = hof[req_id]                                 # primary request per op
+    is_h = h >= 0
+    # duplicates without an explicit primary link: legacy adjacency
+    # rule (their stagnant slot is the preceding payload op's)
+    chan = np.where(~payload & ~is_h, (chan + 1) % channels, chan)
+    if is_h.any():
+        # hedge_of-linked duplicates mirror op j of their primary
+        # request shifted one channel AND one way.  The channel shift is
+        # the replica-read rule; the way shift keeps the duplicate off
+        # the chip the stripe is about to reuse for the *next* payload
+        # op — without it every duplicate queues on exactly that chip
+        # and (FCFS issue being serial through the controller) convoys
+        # the whole stream, inverting the mitigation it exists to
+        # provide.
+        reps = np.asarray(stream.n_pages, np.int64)
+        starts = np.cumsum(reps) - reps             # [R] first-op index
+        pos = np.arange(len(cls)) - starts[req_id]
+        src = starts[np.clip(h, 0, None)] + pos
+        chan = np.where(is_h, (chan[src] + 1) % channels, chan)
+        way = np.where(is_h, (way[src] + 1) % ways, way)
+    # duplicates break the slot sequence the closed form needs: the
+    # parity is _finalize's per-chip rank; arrivals ride on top
     trace = dataclasses.replace(
-        _finalize(cls, chan, way, channels, ways,
-                  payload=None if payload.all() else payload),
+        _finalize(cls, chan, way, channels, ways, payload=payload),
         arrival_us=None if not np.any(arrival) else arrival)
-    return LoweredWorkload(
-        trace=trace, request_id=req_id,
-        request_arrival_us=np.asarray(stream.arrival_us, np.float32))
+    return LoweredWorkload(trace=trace, request_id=req_id,
+                           request_arrival_us=arrival_out,
+                           ranked_ops=len(cls))
 
 
 def lower_ops(cls, arrival_us, channels: int, ways: int,
               policy: str = "stripe", payload=None) -> OpTrace:
     """Lower an already-expanded *op* stream (per-op class/arrival
-    arrays) to a placed ``OpTrace`` under a static policy.
+    arrays) to a placed ``OpTrace`` under a static policy:
+    :func:`lower_ops_chunk` at slot offset 0.
 
     This is the lowering the FTL stage uses (DESIGN.md §2.10): its
     translated stream interleaves host ops with GC relocation ops, and
     every op — payload or not — advances the placement slot, so GC
     traffic competes with host traffic for channels and ways exactly
     like the dynamic dispatch fold makes it compete for occupancy.
-    (``lower_static`` differs deliberately: there, non-payload ops are
-    hedged *duplicates* that mirror their primary's placement instead
-    of consuming a slot.)"""
-    if policy_is_dynamic(policy):
-        raise ValueError(
-            f"sched policy {policy!r} is dynamic — it cannot be lowered "
-            "offline; run it through Simulator.run(workload=...) / "
-            "sim.dispatch_trace (engines with the 'dispatch' capability)")
-    cls = np.asarray(cls, np.int32)
-    arrival = np.asarray(arrival_us, np.float32)
-    slots = np.arange(len(cls))
-    if policy == "stripe":
-        chan = slots % channels
-        way = (slots // channels) % ways
-    else:                                           # "round_robin": way-first
-        way = slots % ways
-        chan = (slots // ways) % channels
-    if payload is not None:
-        payload = np.asarray(payload, bool)
-        if payload.all():
-            payload = None
-    return dataclasses.replace(
-        _finalize(cls, chan, way, channels, ways, payload=payload),
-        arrival_us=None if not np.any(arrival) else arrival)
+    Since every op advances a slot, op ``t``'s MLC parity is the closed
+    form ``(t // (channels * ways)) % 2``, ``_finalize``'s per-chip rank
+    without the sort.  (``lower_static`` differs deliberately: there,
+    non-payload ops are hedged *duplicates* that mirror their primary's
+    placement instead of consuming a slot.)"""
+    return lower_ops_chunk(cls, arrival_us, channels, ways, policy,
+                           payload=payload)[0]
 
 
 def apply_faults(trace: OpTrace, spec: FaultSpec, table=None, *,
@@ -241,33 +243,30 @@ def lower_ops_chunk(cls, arrival_us, channels: int, ways: int,
     ``channels * ways``-th slot, so op ``s``'s per-chip ordinal is
     ``s // (channels * ways)`` and its MLC parity is that ordinal mod 2
     — regression-pinned against ``_finalize`` in the sched tests.
+    Chip and parity thus repeat every ``2 * channels * ways`` slots, so
+    one such period is placed and repeated over the chunk.
 
     Returns ``(trace, next_offset)``; feed ``next_offset`` to the next
     chunk.  This is what lets the FTL translation stream through
     ``trace_chunk_fold`` (DESIGN.md §2.11) without materialising the
     full aged op trace."""
-    if policy_is_dynamic(policy):
-        raise ValueError(
-            f"sched policy {policy!r} is dynamic — it cannot be lowered "
-            "offline; run it through Simulator.run(workload=...) / "
-            "sim.dispatch_trace (engines with the 'dispatch' capability)")
+    _require_static(policy)
+    _check_geometry(channels, ways)
     cls = np.asarray(cls, np.int32)
     arrival = np.asarray(arrival_us, np.float32)
-    slots = slot_offset + np.arange(len(cls))
-    if policy == "stripe":
-        chan = slots % channels
-        way = (slots // channels) % ways
-    else:                                           # "round_robin": way-first
-        way = slots % ways
-        chan = (slots // ways) % channels
+    period = 2 * channels * ways
+    slots = slot_offset + np.arange(period)
+    chan, way = _place(slots, channels, ways, policy)
     parity = (slots // (channels * ways)) % 2
+    reps = -(-len(cls) // period)
+    chan, way, parity = (np.tile(a.astype(np.int32), reps)[:len(cls)]
+                         for a in (chan, way, parity))
     if payload is not None:
         payload = np.asarray(payload, bool)
         if payload.all():
             payload = None
     trace = OpTrace(
-        cls=cls, channel=chan.astype(np.int32), way=way.astype(np.int32),
-        parity=parity.astype(np.int32), channels=channels, ways=ways,
-        payload=payload,
+        cls=cls, channel=chan, way=way, parity=parity,
+        channels=channels, ways=ways, payload=payload,
         arrival_us=None if not np.any(arrival) else arrival)
     return trace, slot_offset + len(cls)

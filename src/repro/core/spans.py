@@ -16,7 +16,10 @@ the calling thread:
     inside it, the aging of a fresh drive (``writes``,
     ``machine_runs``);
 ``ssd.lower``
-    the scheduler's static lowering and the trace check (``ops``);
+    the scheduler's static lowering and the trace check (``ops``;
+    ``ranked_ops``: the ops whose MLC page parity took the per-chip
+    rank of ``core/trace._finalize``, 0 where every op advances a
+    placement slot and the closed form gives it);
 ``ssd.stage``
     host arrays to device arguments at an engine call (``steps``);
 ``ssd.fold``

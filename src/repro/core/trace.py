@@ -171,22 +171,38 @@ def op_class_table(cfg: SSDConfig) -> OpClassTable:
 # ---------------------------------------------------------------------------
 
 
-def _finalize(cls, channel, way, channels, ways, payload=None):
-    """Derive per-chip page parity: the i-th op on a chip programs the
-    lower (even i) or upper (odd i) page of an MLC pair."""
+def _check_geometry(channels: int, ways: int) -> None:
     assert 1 <= channels <= MAX_CHANNELS, \
         f"channels must be in [1, {MAX_CHANNELS}], got {channels}"
     assert 1 <= ways <= MAX_WAYS, \
         f"ways must be in [1, {MAX_WAYS}], got {ways}"
+
+
+def _finalize(cls, channel, way, channels, ways, payload=None):
+    """Derive per-chip page parity: the i-th op on a chip programs the
+    lower (even i) or upper (odd i) page of an MLC pair.
+
+    An op's rank ``i`` is its position among the ops on its chip
+    ``(channel, way)``, in trace order.  A stable sort by the chip key
+    ``channel * ways + way`` (``int16``, so numpy's stable sort is a
+    radix sort) lines each chip's ops up in trace order; the rank is an
+    op's distance from the head of its chip's run.  Placements in which
+    every op advances one slot of a round-robin have the closed form
+    ``(slot // (channels * ways)) % 2`` instead
+    (``repro.core.sched.lower_ops_chunk``), which needs no sort."""
+    _check_geometry(channels, ways)
     cls = np.asarray(cls, np.int32)
     channel = np.asarray(channel, np.int32)
     way = np.asarray(way, np.int32)
-    parity = np.zeros_like(cls)
-    counts = np.zeros((channels, ways), np.int64)
-    for t in range(len(cls)):
-        c, w = channel[t], way[t]
-        parity[t] = counts[c, w] % 2
-        counts[c, w] += 1
+    key = (channel * ways + way).astype(np.int16)
+    order = np.argsort(key, kind="stable")
+    key = key[order]
+    t = np.arange(len(cls))
+    head = np.ones(len(cls), bool)
+    head[1:] = key[1:] != key[:-1]
+    rank = t - np.maximum.accumulate(np.where(head, t, 0))
+    parity = np.empty_like(cls)
+    parity[order] = rank % 2
     return OpTrace(cls=cls, channel=channel, way=way, parity=parity,
                    channels=channels, ways=ways,
                    payload=(None if payload is None
