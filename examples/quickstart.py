@@ -1,20 +1,16 @@
-"""Quickstart: the paper's result in 30 lines + a tiny training run.
+"""Quickstart: the paper's headline result and what it buys an SSD.
 
     PYTHONPATH=src python examples/quickstart.py
 """
-
-import jax
 
 from repro.api import SSDConfig, steady_bandwidth_mb_s
 from repro.core import timing
 from repro.core.interface import InterfaceKind
 from repro.core.nand import CellType
-from repro.configs import get_arch, smoke_batch
-from repro.models.transformer import init_params, loss_fn
 
 
 def main():
-    # 1) the paper's headline: CONV 50 MHz vs PROPOSED 83 MHz DDR ...
+    # the paper's headline: CONV 50 MHz vs PROPOSED 83 MHz DDR ...
     clocks = timing.derive_paper_clocks()
     print(f"CONV     t_P,min = {clocks.conv_t_p_ns:.2f} ns -> {clocks.conv_mhz:.0f} MHz SDR")
     print(f"PROPOSED t_P,min = {clocks.prop_t_p_ns:.2f} ns -> {clocks.prop_mhz:.0f} MHz DDR")
@@ -24,13 +20,6 @@ def main():
         cfg = SSDConfig(interface=kind, cell=CellType.SLC, ways=16)
         print(f"  {kind.value:10s} 16-way SLC read : "
               f"{steady_bandwidth_mb_s(cfg, 'read'):7.1f} MB/s")
-
-    # 2) one forward/backward through a zoo architecture (reduced config)
-    arch = get_arch("qwen2-0.5b")
-    params = init_params(arch.smoke, jax.random.PRNGKey(0))
-    loss, metrics = loss_fn(arch.smoke, params, smoke_batch(arch.smoke))
-    print(f"\nqwen2-0.5b (smoke config) loss: {float(loss):.3f} "
-          f"({int(metrics['tokens'])} tokens)")
 
 
 if __name__ == "__main__":

@@ -1,5 +1,5 @@
 """Design-space exploration with the paper's SSD model (paper §5.3.2 +
-capacity planning for the training stack), extended to the mixed
+capacity planning for the storage tier), extended to the mixed
 read/write op-trace workloads the paper could not express.
 
     PYTHONPATH=src python examples/ssd_design_space.py
@@ -14,11 +14,10 @@ from repro.core.interface import InterfaceKind
 from repro.core.nand import CellType
 from repro.core.sim import SSDConfig
 from repro.core.trace import checkpoint_trace, datapipe_trace
-from repro.storage.kvoffload import plan_kv_offload
+from repro.storage.kvoffload import KV_SHAPES, plan_kv_offload
 from repro.storage.ssd_model import (compare_interfaces,
                                      compare_interfaces_trace, plan_geometry,
                                      plan_geometry_for_trace)
-from repro.configs import get_arch
 
 
 def main():
@@ -186,7 +185,7 @@ def main():
 
     print("\n== KV offload feasibility at 524288-token decode ==")
     for arch_id in ("qwen2-0.5b", "recurrentgemma-9b", "xlstm-350m"):
-        plan = plan_kv_offload(get_arch(arch_id).config, 524288)
+        plan = plan_kv_offload(KV_SHAPES[arch_id], 524288)
         print(f"  {plan.note}")
 
 

@@ -1,8 +1,8 @@
-"""Production mesh definitions (TPU v5e-256 pods).
+"""The design-point mesh the simulator sweeps shard over.
 
-``make_production_mesh`` is a function (not a module constant) so that
-importing this module never touches JAX device state — required because
-the dry-run must set ``XLA_FLAGS=--xla_force_host_platform_device_count``
+``make_points_mesh`` is a function (not a module constant) so that
+importing this module never touches JAX device state: a process that
+sets ``XLA_FLAGS=--xla_force_host_platform_device_count`` must do so
 before the first JAX initialisation.
 """
 
@@ -15,40 +15,22 @@ from jax.sharding import PartitionSpec as P
 POINTS_AXIS = "points"
 
 
-def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axes: callers index, slice and gather
-    sharded arrays as plain arrays, which explicit (typed) axes — the
-    default of ``jax.make_mesh`` — would reject."""
-    return jax.make_mesh(shape, axes,
-                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
-
-
-def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
-    """Development mesh over whatever devices exist (tests / examples)."""
-    n = len(jax.devices())
-    assert n % model == 0, (n, model)
-    return _auto_mesh((n // model, model), ("data", "model"))
-
-
 def make_points_mesh() -> jax.sharding.Mesh | None:
     """1-D ``("points",)`` mesh over every device — the design-point /
     batch-row sharding axis of the simulator sweeps (DESIGN.md §2.7).
     Returns None with a single device so the sweep entry points fall
     back to their plain vmap path instead of paying shard_map overhead
-    for nothing.  A function, like the meshes above, so importing never
-    touches JAX device state (``--xla_force_host_platform_device_count``
-    must win the race)."""
+    for nothing.  A function, so importing never touches JAX device
+    state (``--xla_force_host_platform_device_count`` must win the
+    race)."""
     n = len(jax.devices())
     if n < 2:
         return None
-    return _auto_mesh((n,), (POINTS_AXIS,))
+    # Auto axes: callers index, slice and gather sharded arrays as plain
+    # arrays, which explicit (typed) axes — the default of
+    # ``jax.make_mesh`` — would reject
+    return jax.make_mesh((n,), (POINTS_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def shard_points(mesh: jax.sharding.Mesh, fn, *, n_sharded: int):

@@ -30,6 +30,7 @@ import dataclasses
 import json
 import pathlib
 import re
+import shutil
 import threading
 import time
 from typing import Any
@@ -63,6 +64,23 @@ def _safe(name: str) -> str:
     return re.sub(r"[^\w\.]", "_", name)
 
 
+def _replace_dir(src: pathlib.Path, dst: pathlib.Path) -> None:
+    """Give ``src`` the name ``dst``.  An existing ``dst`` moves aside
+    first and goes only once ``src`` holds the name (or comes back if
+    the rename fails), so a step is never seen half-written."""
+    old = dst.with_name(dst.name + ".old")
+    shutil.rmtree(old, ignore_errors=True)
+    if dst.exists():
+        dst.rename(old)
+    try:
+        src.rename(dst)
+    except BaseException:
+        if old.exists():
+            old.rename(dst)
+        raise
+    shutil.rmtree(old, ignore_errors=True)
+
+
 @dataclasses.dataclass
 class SaveResult:
     step: int
@@ -83,12 +101,14 @@ class CheckpointEngine:
         self.keep = keep
         self._pending: threading.Thread | None = None
         self._last: SaveResult | None = None
+        self._error: Exception | None = None
 
     # -- save ---------------------------------------------------------------
 
     def save(self, step: int, state: Any, *, extra: dict | None = None,
              blocking: bool = False) -> None:
-        """Snapshot to host memory synchronously, write asynchronously."""
+        """Snapshot to host memory synchronously, write asynchronously.
+        A failure of the previous save raises here (see :meth:`wait`)."""
         host = {k: np.asarray(v) for k, v in _flatten(state).items()}
         self.wait()
         t = threading.Thread(target=self._write, args=(step, host, extra or {}),
@@ -99,9 +119,38 @@ class CheckpointEngine:
             self.wait()
 
     def _write(self, step: int, host: dict[str, np.ndarray], extra: dict):
-        t0 = time.time()
+        """The save thread: write the step under a temporary name, then
+        give it the step's name.  A failure is kept for :meth:`wait`."""
         out = self.dir / f"step_{step:08d}.tmp"
-        out.mkdir(parents=True, exist_ok=True)
+        try:
+            t0 = time.time()
+            shutil.rmtree(out, ignore_errors=True)   # a failed save's leftovers
+            nbytes = self._write_chunks(out, step, host, extra)
+            _replace_dir(out, self.dir / f"step_{step:08d}")
+            wall = time.time() - t0
+            # the save is a request-level workload (a zero-arrival write
+            # burst: the writer queues every chunk at once), lowered by
+            # the static stripe scheduler onto the tier's geometry and
+            # priced on the joint multi-channel simulation; the placement
+            # depends only on cell/geometry, not on the interface kind,
+            # so one per-interface fan-out through the cached Simulator
+            # sessions prices all three
+            requests = checkpoint_requests(nbytes, self.ssd)
+            tr = lower_static(requests, self.ssd.channels, self.ssd.ways).trace
+            modeled = {kind: est.seconds for kind, est in
+                       estimate_trace_interfaces(tr, self.ssd,
+                                                 total_bytes=nbytes).items()}
+            self._last = SaveResult(step, nbytes, wall, modeled)
+            self._gc()
+        except Exception as e:   # raised by wait(), in the caller's thread
+            shutil.rmtree(out, ignore_errors=True)
+            self._error = e
+
+    def _write_chunks(self, out: pathlib.Path, step: int,
+                      host: dict[str, np.ndarray], extra: dict) -> int:
+        """Write one step's chunks and manifest under ``out``; returns
+        the bytes written."""
+        out.mkdir(parents=True)
         chunks: list[tuple[pathlib.Path, np.ndarray]] = []
         manifest: dict[str, Any] = {"step": step, "extra": extra, "leaves": {}}
         for path, arr in host.items():
@@ -117,40 +166,25 @@ class CheckpointEngine:
                 d.mkdir(exist_ok=True)
                 chunks.append((d / f"{_safe(path)}__c{j}.npy",
                                flat[j * per:(j + 1) * per]))
-        nbytes = sum(int(c.nbytes) for _, c in chunks)
         # ways = outstanding buffers per channel writer
         with cf.ThreadPoolExecutor(max_workers=self.channels * self.ways) as ex:
             list(ex.map(lambda fc: np.save(fc[0], fc[1]), chunks))
         (out / "MANIFEST.json").write_text(json.dumps(manifest))
-        final = self.dir / f"step_{step:08d}"
-        out.rename(final)
-        wall = time.time() - t0
-        # the save is a request-level workload (a zero-arrival write
-        # burst: the writer queues every chunk at once), lowered by the
-        # static stripe scheduler onto the tier's geometry and priced on
-        # the joint multi-channel simulation; the placement depends only
-        # on cell/geometry, not on the interface kind, so one
-        # per-interface fan-out through the cached Simulator sessions
-        # prices all three
-        requests = checkpoint_requests(nbytes, self.ssd)
-        tr = lower_static(requests, self.ssd.channels, self.ssd.ways).trace
-        modeled = {kind: est.seconds for kind, est in
-                   estimate_trace_interfaces(tr, self.ssd,
-                                             total_bytes=nbytes).items()}
-        self._last = SaveResult(step, nbytes, wall, modeled)
-        self._gc()
+        return sum(int(c.nbytes) for _, c in chunks)
 
     def _gc(self):
         steps = sorted(self.dir.glob("step_????????"))
         for old in steps[:-self.keep]:
-            for f in sorted(old.rglob("*"), reverse=True):
-                f.unlink() if f.is_file() else f.rmdir()
-            old.rmdir()
+            shutil.rmtree(old)
 
     def wait(self) -> SaveResult | None:
+        """Wait for the save in flight; raise what made it fail."""
         if self._pending is not None:
             self._pending.join()
             self._pending = None
+        if self._error is not None:
+            err, self._error = self._error, None
+            raise err
         return self._last
 
     # -- restore (elastic) ----------------------------------------------------

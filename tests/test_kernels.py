@@ -5,60 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import attention_reference
 from repro.kernels.maxplus.kernel import maxplus_fold_kernel
 from repro.kernels.maxplus.ref import maxplus_fold_ref
-from repro.kernels.rglru.ops import rglru_linear_scan
-from repro.kernels.rglru.ref import rglru_scan_ref
 
 KEY = jax.random.PRNGKey(7)
-
-
-# --- flash attention ---------------------------------------------------------
-
-FLASH_CASES = [
-    # b, h, kvh, sq, sk, d, causal, window, dtype, bq, bk
-    (2, 4, 2, 128, 128, 64, True, None, jnp.float32, 64, 64),
-    (1, 4, 1, 256, 256, 64, True, 64, jnp.float32, 64, 64),
-    (2, 2, 2, 128, 128, 32, False, None, jnp.bfloat16, 64, 64),
-    (1, 6, 2, 128, 256, 64, True, None, jnp.float32, 64, 64),  # q_offset
-    (1, 8, 8, 64, 64, 128, True, None, jnp.float32, 32, 32),   # MHA
-    (1, 2, 1, 64, 64, 16, True, 16, jnp.bfloat16, 64, 64),     # tiny window
-]
-
-
-@pytest.mark.parametrize("case", FLASH_CASES, ids=[str(i) for i in range(len(FLASH_CASES))])
-def test_flash_attention_matches_reference(case):
-    b, h, kvh, sq, sk, d, causal, window, dtype, bq, bk = case
-    ks = jax.random.split(jax.random.fold_in(KEY, sq + sk + d), 3)
-    q = jax.random.normal(ks[0], (b, h, sq, d), dtype)
-    k = jax.random.normal(ks[1], (b, kvh, sk, d), dtype)
-    v = jax.random.normal(ks[2], (b, kvh, sk, d), dtype)
-    off = sk - sq
-    out = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=bq, block_k=bk, q_offset=off)
-    ref = attention_reference(q, k, v, causal=causal, window=window, q_offset=off)
-    tol = 2.5e-2 if dtype == jnp.bfloat16 else 5e-5
-    err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))))
-    assert err < tol, err
-
-
-def test_flash_attention_grouped_layout():
-    """Model-native [B, S, kvH, G, D] layout round-trips correctly."""
-    ks = jax.random.split(KEY, 3)
-    b, s, kvh, g, d = 2, 128, 2, 3, 32
-    q = jax.random.normal(ks[0], (b, s, kvh, g, d), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, kvh, d), jnp.float32)
-    v = jax.random.normal(ks[2], (b, s, kvh, d), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
-    qx = q.transpose(0, 2, 3, 1, 4).reshape(b, kvh * g, s, d)
-    ref = attention_reference(qx, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
-    ref = ref.reshape(b, kvh, g, s, d).transpose(0, 3, 1, 2, 4)
-    assert float(jnp.max(jnp.abs(out - ref))) < 5e-5
-
-
-# --- maxplus -----------------------------------------------------------------
 
 
 @pytest.mark.parametrize("b,p,n,t", [(4, 8, 18, 40), (130, 4, 18, 17), (1, 2, 6, 9)])
@@ -70,24 +20,3 @@ def test_maxplus_kernel_matches_ref(b, p, n, t):
     out = maxplus_fold_kernel(mats, s0, t_steps=t)
     ref = maxplus_fold_ref(mats, s0, t_steps=t)
     assert np.allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-3)
-
-
-# --- rglru scan --------------------------------------------------------------
-
-
-@pytest.mark.parametrize("b,s,r,bs,dtype", [
-    (2, 512, 128, 128, jnp.float32),
-    (1, 256, 256, 64, jnp.float32),
-    (2, 128, 128, 128, jnp.bfloat16),
-    (1, 64, 128, 32, jnp.float32),
-    (3, 96, 128, 96, jnp.float32),
-])
-def test_rglru_kernel_matches_associative_scan(b, s, r, bs, dtype):
-    k1, k2 = jax.random.split(jax.random.fold_in(KEY, s + r))
-    a = jax.random.uniform(k1, (b, s, r), jnp.float32, 0.85, 0.999).astype(dtype)
-    x = jax.random.normal(k2, (b, s, r), jnp.float32).astype(dtype)
-    out = rglru_linear_scan(a, x, block_s=bs)
-    ref = rglru_scan_ref(a, x)
-    tol = 6e-2 if dtype == jnp.bfloat16 else 2e-4
-    err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))))
-    assert err < tol, err

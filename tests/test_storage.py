@@ -1,18 +1,22 @@
 """Checkpoint engine, data pipeline, SSD pricing and KV-offload planning."""
 
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs import get_arch
 from repro.core.interface import InterfaceKind
 from repro.core.sim import SSDConfig
 from repro.storage.checkpoint import CheckpointEngine
 from repro.storage.datapipe import (FileBackedTokens, StripedTokenStore,
                                     SyntheticTokens, pipeline_io_trace)
-from repro.storage.kvoffload import plan_kv_offload
+from repro.storage.kvoffload import KV_SHAPES, kv_bytes_per_token, plan_kv_offload
 from repro.storage.ssd_model import compare_interfaces, estimate_io, plan_geometry
 
 
@@ -42,6 +46,70 @@ def test_checkpoint_gc_and_latest(tmp_path):
     assert eng.latest_step() == 3
     steps = sorted(p.name for p in tmp_path.glob("step_*"))
     assert len(steps) == 2  # keep=2
+
+
+def test_checkpoint_resave_replaces_step(tmp_path):
+    eng = CheckpointEngine(tmp_path)
+    first = {"w": jnp.zeros((8, 4))}
+    second = {"w": jnp.arange(32, dtype=jnp.float32).reshape(8, 4)}
+    eng.save(4, first, extra={"v": 1}, blocking=True)
+    eng.save(4, second, extra={"v": 2}, blocking=True)
+    step, restored, extra = eng.restore(template=second)
+    assert step == 4 and extra == {"v": 2}
+    np.testing.assert_array_equal(restored["w"], np.asarray(second["w"]))
+    assert sorted(p.name for p in tmp_path.iterdir()) == ["step_00000004"]
+
+
+def test_checkpoint_failed_write_raises(tmp_path, monkeypatch):
+    """A write that fails in the save thread raises from ``wait()``, or
+    else from the next ``save()``, and leaves no partial step behind."""
+    eng = CheckpointEngine(tmp_path)
+
+    def full_disk(*args, **kwargs):
+        raise OSError(28, "No space left on device")
+
+    with monkeypatch.context() as m:
+        m.setattr(np, "save", full_disk)
+        eng.save(1, _state())
+        with pytest.raises(OSError, match="No space"):
+            eng.wait()
+        eng.save(2, _state())
+        with pytest.raises(OSError, match="No space"):
+            eng.save(3, _state(), blocking=True)
+    assert list(tmp_path.iterdir()) == []
+    eng.save(3, _state(), blocking=True)        # the error is not sticky
+    assert eng.latest_step() == 3
+
+
+def test_elastic_reshard_subprocess():
+    """Checkpoint on a (4,2) mesh, restore onto (2,2) and (8,1) — elastic."""
+    code = textwrap.dedent("""
+        import os, tempfile
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.storage.checkpoint import CheckpointEngine, place_on_mesh
+
+        state = {"w": jnp.arange(512, dtype=jnp.float32).reshape(16, 32)}
+        d = tempfile.mkdtemp()
+        m1 = jax.make_mesh((4, 2), ("data", "model"))
+        sharded = jax.device_put(state["w"], NamedSharding(m1, P("data", "model")))
+        eng = CheckpointEngine(d, channels=2)
+        eng.save(1, {"w": sharded}, blocking=True)
+
+        for shape in ((2, 2), (8, 1)):   # elastic: fewer / rearranged devices
+            m2 = jax.make_mesh(shape, ("data", "model"))
+            step, host, _ = eng.restore(template={"w": state["w"]})
+            placed = place_on_mesh(host, {"w": NamedSharding(m2, P("data", "model"))})
+            np.testing.assert_array_equal(np.asarray(placed["w"]),
+                                          np.asarray(state["w"]))
+        print("ELASTIC_OK")
+    """)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, cwd=os.path.dirname(os.path.dirname(__file__)))
+    assert "ELASTIC_OK" in r.stdout, r.stdout + r.stderr
 
 
 def test_checkpoint_modeled_ssd_stall(tmp_path):
@@ -118,10 +186,34 @@ def test_pipeline_emits_priceable_trace(tmp_path):
 
 
 def test_kv_offload_planning():
-    qwen = plan_kv_offload(get_arch("qwen2-0.5b").config, 524288)
+    qwen = plan_kv_offload(KV_SHAPES["qwen2-0.5b"], 524288)
     assert qwen.applicable
     assert qwen.tokens_per_s["proposed"] > 1.5 * qwen.tokens_per_s["conv"]
-    xl = plan_kv_offload(get_arch("xlstm-350m").config, 524288)
+    xl = plan_kv_offload(KV_SHAPES["xlstm-350m"], 524288)
     assert not xl.applicable                      # attention-free
-    rg = plan_kv_offload(get_arch("recurrentgemma-9b").config, 524288)
+    rg = plan_kv_offload(KV_SHAPES["recurrentgemma-9b"], 524288)
     assert not rg.applicable                      # windowed-only attention
+
+
+#: (hot, cold) KV bytes a token adds, as the published model configs
+#: gave them: 2 (K and V) x kv heads x head dim x 2 bytes (bf16) x full
+#: attention layers.
+KV_BYTES_PER_TOKEN = {
+    "qwen2-0.5b": (0, 12288),
+    "minicpm-2b": (0, 368640),
+    "granite-3-2b": (0, 81920),
+    "starcoder2-3b": (0, 30720),
+    "llama4-maverick-400b-a17b": (0, 196608),
+    "granite-moe-3b-a800m": (0, 65536),
+    "musicgen-medium": (0, 294912),
+    "recurrentgemma-9b": (0, 0),
+    "qwen2-vl-2b": (0, 28672),
+    "xlstm-350m": (0, 0),
+}
+
+
+@pytest.mark.parametrize("shape_id", sorted(KV_BYTES_PER_TOKEN))
+def test_kv_bytes_per_token_pinned(shape_id):
+    assert KV_SHAPES[shape_id].name == shape_id
+    assert kv_bytes_per_token(KV_SHAPES[shape_id]) == \
+        KV_BYTES_PER_TOKEN[shape_id]
